@@ -712,41 +712,6 @@ def bench_planner() -> None:
     write_rows("planner_latency.csv", "planner_")
 
 
-# ---------------------------------------------------------------- kernels
-def bench_kernels() -> None:
-    """Interpret-mode sanity timing + analytic v5e roofline per kernel."""
-    import jax
-    import jax.numpy as jnp
-    from repro.kernels import ops
-    from repro.core.tiers import V5E_PEAK_FLOPS_BF16, V5E_HBM_BW
-
-    key = jax.random.PRNGKey(0)
-    B, K, G, S, D = 1, 2, 2, 256, 128
-    q = jax.random.normal(key, (B, K, G, S, D), jnp.float32)
-    kv = jax.random.normal(key, (B, K, S, D), jnp.float32)
-    t0 = time.perf_counter()
-    ops.flash_attention(q, kv, kv, force_pallas=True,
-                        interpret=True).block_until_ready()
-    us = (time.perf_counter() - t0) * 1e6
-    flops = 4 * B * K * G * S * S * D
-    bytes_ = 2 * (q.size + 2 * kv.size + q.size)
-    emit("kernel_flash_attention", us,
-         f"tpu_roofline_us="
-         f"{max(flops / V5E_PEAK_FLOPS_BF16, bytes_ / V5E_HBM_BW) * 1e6:.2f}")
-
-    x = jax.random.normal(key, (512, 1024), jnp.float32)
-    w = jax.random.normal(key, (1024, 512), jnp.float32)
-    t0 = time.perf_counter()
-    ops.tiered_matmul(x, w, force_pallas=True,
-                      interpret=True).block_until_ready()
-    us = (time.perf_counter() - t0) * 1e6
-    flops = 2 * 512 * 1024 * 512
-    bytes_ = 2 * (x.size + w.size + 512 * 512)
-    emit("kernel_tiered_matmul", us,
-         f"tpu_roofline_us="
-         f"{max(flops / V5E_PEAK_FLOPS_BF16, bytes_ / V5E_HBM_BW) * 1e6:.2f}")
-
-
 BENCHES = {
     "fig2_3": bench_tier_sweep,
     "fig4": bench_object_placement,
@@ -761,7 +726,6 @@ BENCHES = {
     "tenants": bench_tenants,
     "multihost": bench_multihost,
     "planner": bench_planner,
-    "kernels": bench_kernels,
 }
 
 

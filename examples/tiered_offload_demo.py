@@ -1,7 +1,7 @@
 """Real tier moves: the Unimem mover relocating actual JAX arrays between
 memory kinds (``device`` <-> ``pinned_host``) with async device_put — the
-production HBM/host path, exercised on the CPU backend (which exposes the
-same memory-kind API).
+production HBM/host path.  The CPU backend exposes the same memory kinds;
+a backend without ``pinned_host`` fails instead of faking the moves.
 
 v2 session API: arrays are registered pytree-natively (leaf byte spans
 recorded), the loop is the ``iteration()``/``phase()`` context managers,
@@ -29,11 +29,8 @@ def main() -> None:
     dev = jax.devices()[0]
     kinds = [m.kind for m in dev.addressable_memories()]
     print("device:", dev, "memories:", kinds)
-    # host tier = pinned_host where the backend offers it (TPU/GPU); on a
-    # backend without it the moves are logical (tier bookkeeping only)
-    host_kind = "pinned_host" if "pinned_host" in kinds else kinds[0]
-
     machine = PAPER_DRAM_NVM
+    host_kind = machine.slow.memory_kind
     rt = UnimemRuntime(machine,
                        RuntimeConfig(fast_capacity_bytes=64 * MB,
                                      enable_partitioning=False,

@@ -30,7 +30,7 @@ All are exact on the same quantized grid and return identical selections.
 from __future__ import annotations
 
 import dataclasses
-from typing import List, Optional, Sequence, Tuple
+from typing import List, Sequence, Tuple
 
 import numpy as np
 
@@ -70,25 +70,14 @@ _JAX_MIN_WORK = 8_000_000       # n * qcap below this: numpy wins w/ no compile
 #: tested) for backends where the jit wins.
 use_jax: bool = False
 _jax_kernels: dict = {}
-_jax_state: Optional[bool] = None    # None = untried, False = unavailable
 
 
 def _jax_dp(values: np.ndarray, qsizes: np.ndarray, qcap: int
-            ) -> Optional[np.ndarray]:
-    """Packed keep table from the jitted scan, or None when jax is
-    unavailable (the numpy path is the behavioural twin, so callers just
-    fall through)."""
-    global _jax_state
-    if _jax_state is False:
-        return None
-    try:
-        import jax
-        import jax.numpy as jnp
-        from jax.experimental import enable_x64
-        _jax_state = True
-    except Exception:       # pragma: no cover - jax is baked into the image
-        _jax_state = False
-        return None
+            ) -> np.ndarray:
+    """Packed keep table from the jitted scan (the numpy path is its
+    behavioural twin)."""
+    import jax
+    import jax.numpy as jnp
 
     n = len(values)
     n_pad = 1 << max(int(np.ceil(np.log2(max(n, 1)))), 0)
@@ -125,7 +114,7 @@ def _jax_dp(values: np.ndarray, qsizes: np.ndarray, qcap: int
     vals[:n] = values
     sizes = np.ones(n_pad, dtype=np.int64)      # v=0 padding is inert
     sizes[:n] = qsizes
-    with enable_x64():
+    with jax.enable_x64(True):
         keep = np.asarray(kernel(vals, sizes))
     return keep[:n]
 
@@ -172,10 +161,9 @@ def solve_arrays(values: np.ndarray, sizes: np.ndarray, capacity_bytes: int,
     if n * qcap > 50_000_000:   # DP too big -> density greedy
         return pos_idx[_greedy_arrays(pvals, psizes, capacity_bytes)]
 
-    keep = None
     if use_jax and n * qcap >= _JAX_MIN_WORK:
         keep = _jax_dp(pvals, qsizes, qcap)
-    if keep is None:
+    else:
         keep = _numpy_dp(pvals, qsizes, qcap)
     # backtrack
     chosen: List[int] = []

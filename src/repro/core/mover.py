@@ -113,13 +113,19 @@ class JaxTierBackend:
         self.machine = machine
 
     def _sharding_for(self, leaf: jax.Array, kind: Optional[str]):
+        """The leaf's sharding in memory ``kind``; raises ValueError when
+        a device of the leaf does not expose that kind (a move that cannot
+        happen must not pass for one)."""
         s = leaf.sharding
         if kind is None:
             return s
-        try:
-            return s.with_memory_kind(kind)
-        except Exception:
-            return s   # backend without memory kinds: logical move only
+        for dev in s.device_set:
+            offered = {m.kind for m in dev.addressable_memories()}
+            if kind not in offered:
+                raise ValueError(
+                    f"device {dev} has no memory kind {kind!r} "
+                    f"(offers {sorted(offered)})")
+        return s.with_memory_kind(kind)
 
     def start_move(self, obj: DataObject, dst: str) -> Any:
         tier = self.machine.fast if dst == "fast" else self.machine.slow
@@ -144,8 +150,7 @@ class JaxTierBackend:
         deadline = _time.monotonic() + timeout
         pending = list(leaves)
         while True:
-            pending = [l for l in pending
-                       if not getattr(l, "is_ready", lambda: True)()]
+            pending = [l for l in pending if not l.is_ready()]
             if not pending:
                 return
             if _time.monotonic() >= deadline:
@@ -186,11 +191,16 @@ class AsyncJaxTierBackend(JaxTierBackend):
     * :meth:`wait` / :meth:`complete` fence one copy with per-leaf
       ``block_until_ready`` (the consuming fence pays only for its own
       object's leaves, not the whole in-flight set).
+
+    ``landed_copies`` (per destination tier) and ``landed_bytes`` count the
+    copies that landed; every one of them moved a payload.
     """
 
     def __init__(self, machine: MachineProfile):
         super().__init__(machine)
         self._open: List[_AsyncJaxCopy] = []
+        self.landed_copies: Dict[str, int] = {"fast": 0, "slow": 0}
+        self.landed_bytes = 0
 
     def start_move(self, obj: DataObject, dst: str,
                    after: Optional[_AsyncJaxCopy] = None) -> Any:
@@ -219,6 +229,8 @@ class AsyncJaxTierBackend(JaxTierBackend):
         if not h.landed:
             h.obj.tier = h.dst
             h.landed = True
+            self.landed_copies[h.dst] += 1
+            self.landed_bytes += sum(l.nbytes for l in h.leaves)
         # drop the handle (and its strong refs to the moved leaves) even
         # when the caller fences via wait/complete and never settles —
         # the FIFO mover does exactly that
@@ -243,14 +255,12 @@ class AsyncJaxTierBackend(JaxTierBackend):
         in-flight evictions off the critical path)."""
         if handle is None or handle.landed:
             return True
-        return all(getattr(l, "is_ready", lambda: True)()
-                   for l in handle.leaves)
+        return all(l.is_ready() for l in handle.leaves)
 
     def settle(self, now: float = 0.0) -> None:
         """Land every copy whose leaves are all ready — without blocking."""
         for h in list(self._open):          # _land prunes as it lands
-            if all(getattr(l, "is_ready", lambda: True)()
-                   for l in h.leaves):
+            if all(l.is_ready() for l in h.leaves):
                 self._land(h)
 
 

@@ -263,7 +263,13 @@ def snap_to_leaf_boundaries(bounds: Sequence[int],
 def partition_object_spans(registry: ObjectRegistry, name: str,
                            boundaries: Sequence[int]) -> List[DataObject]:
     """Split ``name`` into chunks at the given byte ``boundaries`` (strictly
-    increasing, ending at the object's size), replacing it in the registry."""
+    increasing, ending at the object's size), replacing it in the registry.
+
+    A payload-carrying object is split only where every chunk can carry
+    its share of the payload: a 1-D array is sliced, a pytree cut only at
+    its leaf boundaries gives each chunk the list of its whole leaves.
+    Otherwise the object is left whole (``[obj]``) — a chunk without a
+    payload would turn every later move of its bytes into a tier flip."""
     obj = registry[name]
     bounds = list(boundaries)
     if not bounds or bounds[-1] != obj.size_bytes:
@@ -275,13 +281,10 @@ def partition_object_spans(registry: ObjectRegistry, name: str,
 
     n_chunks = len(bounds)
     payloads: List[Optional[object]] = [None] * n_chunks
-    if obj.payload is not None and hasattr(obj.payload, "ndim") \
-            and getattr(obj.payload, "ndim", 0) == 1:
-        arr = obj.payload
-        n_el = arr.shape[0]
-        cuts = [0] + [round(b * n_el / obj.size_bytes) for b in bounds]
-        cuts[-1] = n_el
-        payloads = [arr[cuts[i]:cuts[i + 1]] for i in range(n_chunks)]
+    if obj.payload is not None:
+        payloads = _split_payload(obj, bounds)
+        if payloads is None:
+            return [obj]
 
     chunks = []
     lo = 0
@@ -293,6 +296,36 @@ def partition_object_spans(registry: ObjectRegistry, name: str,
         lo = hi
     registry.remove(name)
     return chunks
+
+
+def _split_payload(obj: DataObject,
+                   bounds: Sequence[int]) -> Optional[List[object]]:
+    """Per-chunk payloads for ``obj`` cut at ``bounds``, or None when the
+    payload cannot be divided there (see :func:`partition_object_spans`)."""
+    arr = obj.payload
+    if getattr(arr, "ndim", None) == 1:
+        n_el = arr.shape[0]
+        cuts = [0] + [round(b * n_el / obj.size_bytes) for b in bounds]
+        cuts[-1] = n_el
+        if any(c2 <= c1 for c1, c2 in zip(cuts, cuts[1:])):
+            return None         # a cut inside one element: nothing to carry
+        return [arr[cuts[i]:cuts[i + 1]] for i in range(len(bounds))]
+    if not obj.leaf_spans:
+        return None
+    import jax
+    leaves = jax.tree_util.tree_leaves(obj.payload)
+    if len(leaves) != len(obj.leaf_spans):
+        return None
+    edges = {off for _, off, _ in obj.leaf_spans} | {obj.size_bytes}
+    if any(b not in edges for b in bounds):
+        return None
+    out: List[object] = []
+    lo = 0
+    for hi in bounds:
+        out.append([leaf for leaf, (_, off, _) in zip(leaves, obj.leaf_spans)
+                    if lo <= off < hi])
+        lo = hi
+    return out
 
 
 def partition_object(registry: ObjectRegistry, name: str,

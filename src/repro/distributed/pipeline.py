@@ -15,7 +15,6 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -66,8 +65,8 @@ def pipeline_forward(layer_fn: Callable, n_stages: int, n_microbatches: int,
                                     jnp.arange(n_ticks))
         return outs
 
-    return shard_map(
+    return jax.shard_map(
         stage_body, mesh=mesh,
         in_specs=(P(stage_axis), P(None)),
         out_specs=P(None),
-        check_rep=False)
+        check_vma=False)

@@ -32,12 +32,19 @@ def _ssd_kernel(la_ref, k_ref, v_ref, q_ref, o_ref, state_scr, *, Q: int):
     v = v_ref[0, 0].astype(jnp.float32)             # (Q, P)
     q = q_ref[0, 0].astype(jnp.float32)             # (Q, N)
 
-    cum = jnp.cumsum(la, axis=1)                    # (1, Q) inclusive
-    cum_t = cum.reshape(Q, 1)
-    # intra-chunk decay mask: exp(cum_i - cum_j) for i >= j else 0
-    seg = cum_t - cum                               # (Q, Q): [i, j]
     rows = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
     cols = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
+    # inclusive cumsum as triangular matmuls (Mosaic has no cumsum), as a
+    # row and as a column: cum[j] = sum_{k<=j} la[k]
+    upper = (rows <= cols).astype(jnp.float32)      # [k, j] = k <= j
+    cum = jax.lax.dot_general(la, upper, (((1,), (0,)), ((), ())),
+                              precision=jax.lax.Precision.HIGHEST,
+                              preferred_element_type=jnp.float32)  # (1, Q)
+    cum_t = jax.lax.dot_general(upper, la, (((0,), (1,)), ((), ())),
+                                precision=jax.lax.Precision.HIGHEST,
+                                preferred_element_type=jnp.float32)  # (Q, 1)
+    # intra-chunk decay mask: exp(cum_i - cum_j) for i >= j else 0
+    seg = cum_t - cum                               # (Q, Q): [i, j]
     mask = jnp.where(rows >= cols, jnp.exp(seg), 0.0)
     scores = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32) * mask

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 For each cell this driver:
@@ -30,6 +27,7 @@ import dataclasses
 import functools
 import json
 import math
+import os
 import re
 import time
 from typing import Any, Dict, Optional, Tuple
@@ -487,6 +485,9 @@ def main() -> None:
                     help="fold the model axis into DP (small-model profile)")
     ap.add_argument("--out", default=None, help="directory for JSON results")
     args = ap.parse_args()
+    # 512 virtual CPU devices for the production meshes; XLA reads the flag
+    # when the backend first starts, which is the first device query below
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
     cells = []
     archs = sorted(ARCHS) if (args.all or not args.arch) else [args.arch]

@@ -13,6 +13,7 @@ import jax
 from ..configs import get_config
 from ..models import lm
 from ..serve.engine import ServeEngine
+from .compile_cache import enable_compile_cache
 
 
 def main() -> None:
@@ -24,6 +25,7 @@ def main() -> None:
     ap.add_argument("--new", type=int, default=32)
     ap.add_argument("--max-seq", type=int, default=256)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -13,6 +13,7 @@ import argparse
 from ..configs import get_config
 from ..optim import AdamWConfig
 from ..train.loop import TrainConfig, train
+from .compile_cache import enable_compile_cache
 
 
 def main() -> None:
@@ -29,6 +30,7 @@ def main() -> None:
     ap.add_argument("--moments", choices=["float32", "bfloat16", "int8"],
                     default="float32")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

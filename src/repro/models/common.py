@@ -128,8 +128,6 @@ def embed_lookup(table: jax.Array, tokens: jax.Array,
       logits) — masked local gather + psum over "model".
     """
     import numpy as np
-    from jax.experimental.shard_map import shard_map
-    from jax.sharding import PartitionSpec as P
 
     mesh = get_mesh_hint()
     if mesh is None:
@@ -181,12 +179,12 @@ def embed_lookup(table: jax.Array, tokens: jax.Array,
             dt = jax.lax.psum(dt, tuple(dp_used))
         return dt.astype(dtype)
 
-    fwd_sm = shard_map(_fwd_local, mesh=mesh,
-                       in_specs=(table_spec, tok_spec),
-                       out_specs=x_spec, check_rep=False)
-    bwd_sm = shard_map(_bwd_local, mesh=mesh,
-                       in_specs=(x_spec, tok_spec),
-                       out_specs=table_spec, check_rep=False)
+    fwd_sm = jax.shard_map(_fwd_local, mesh=mesh,
+                           in_specs=(table_spec, tok_spec),
+                           out_specs=x_spec, check_vma=False)
+    bwd_sm = jax.shard_map(_bwd_local, mesh=mesh,
+                           in_specs=(x_spec, tok_spec),
+                           out_specs=table_spec, check_vma=False)
 
     @jax.custom_vjp
     def _lookup(t, tok):

@@ -4,8 +4,9 @@
 lowers for ``decode_32k`` / ``long_500k``.  The engine below drives it for
 real batches (prefill = scanned decode, which works uniformly across the
 attention / hybrid / xlstm cache families) and integrates the Unimem
-runtime: KV blocks are registered as target data objects so cold cache
-blocks can live on the host tier.
+runtime: params and the KV cache are registered as data objects by size
+only (jit owns the buffers), so the runtime profiles and plans them but
+moves none of their bytes.
 """
 
 from __future__ import annotations
@@ -94,12 +95,15 @@ class ServeEngine:
               else contextlib.nullcontext()):
             tok = prompts[:, 0]
             out = [prompts]
-            # prefill by scanned decode (uniform across cache families)
+            # prefill by scanned decode (uniform across cache families).
+            # Each phase ends on block_until_ready of its last output, so
+            # the runtime times the device's work, not its enqueue.
             with self._phase("prefill"):
                 for i in range(P):
                     nxt, _, cache = self.step(self.params, cache,
                                               prompts[:, i], jnp.int32(i))
                     self.stats.prefill_tokens += B
+                jax.block_until_ready((nxt, cache))
             tok = nxt
             gen = []
             with self._phase("decode"):
@@ -109,4 +113,5 @@ class ServeEngine:
                                               jnp.int32(P + j))
                     tok = nxt
                     self.stats.decode_tokens += B
+                jax.block_until_ready((tok, cache))
             return jnp.concatenate(out + gen, axis=1)

@@ -99,6 +99,8 @@ def train(cfg: ArchConfig, tcfg: TrainConfig,
                 batch = data.batch_at(step)
             with rt.phase("step") if rt else contextlib.nullcontext():
                 params, opt_state, metrics = step_fn(params, opt_state, batch)
+                # fence the step's outputs: the phase time is device time
+                jax.block_until_ready((params, opt_state, metrics))
                 loss = float(metrics["loss"])
             with rt.phase("ckpt") if rt else contextlib.nullcontext():
                 if ckpt is not None \

@@ -412,6 +412,8 @@ def test_async_jax_backend_lands_on_settle_or_wait():
     # settle after landing is a no-op
     b.settle(0.0)
     assert obj.tier == "fast"
+    assert b.landed_copies == {"fast": 1, "slow": 0}
+    assert b.landed_bytes == 1024
     # logical (payload-free) objects flip immediately
     o2 = reg.alloc("y", 1024)
     assert b.start_move(o2, "fast") is None
@@ -599,3 +601,20 @@ def test_async_backend_through_runtime_end_to_end():
     assert rt.plan is not None
     assert hot.tier == "fast"
     assert cold.tier == "slow"
+
+
+@pytest.mark.parametrize("backend_cls", [JaxTierBackend, AsyncJaxTierBackend])
+def test_jax_backends_refuse_a_memory_kind_the_device_lacks(backend_cls):
+    """A move to a memory kind the device does not expose raises; it is
+    never turned into a tier flip that moved no byte."""
+    import dataclasses
+    import jax.numpy as jnp
+    machine = dataclasses.replace(
+        MACHINE, slow=dataclasses.replace(MACHINE.slow,
+                                          memory_kind="no_such_kind"))
+    reg = ObjectRegistry()
+    obj = reg.alloc("x", 256, payload=jnp.ones((64,), jnp.float32),
+                    tier="fast")
+    with pytest.raises(ValueError, match="no_such_kind"):
+        backend_cls(machine).start_move(obj, "slow")
+    assert obj.tier == "fast"

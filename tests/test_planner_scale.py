@@ -150,23 +150,24 @@ def test_solve_arrays_matches_reference(seed):
     assert [its[i].name for i in idx] == knapsack.solve_reference(its, cap)
 
 
-def test_solve_arrays_jax_path_matches_reference():
+def test_solve_arrays_jax_path_matches_reference(monkeypatch):
     """Force the jitted lax.scan DP (off by default on CPU) above its
     work threshold and require the bit-packed keep rows to reproduce the
-    reference selection exactly."""
-    pytest.importorskip("jax")
+    reference selection exactly.  The numpy DP is made to fail, so the
+    selection can only have come from the jitted kernel."""
     rng = random.Random(7)
     its = [knapsack.Item(f"o{i}", rng.uniform(-0.5, 2.0),
-                         rng.randint(1, 4) * MB) for i in range(600)]
-    cap = 256 * MB      # n * qcap ~ 9.8M cells: above _JAX_MIN_WORK
+                         rng.randint(1, 4) * MB) for i in range(800)]
+    cap = 256 * MB      # ~640 positive items x 16k cells: above _JAX_MIN_WORK
     values = np.array([it.value for it in its], dtype=np.float64)
     sizes = np.array([it.size_bytes for it in its], dtype=np.int64)
-    old = knapsack.use_jax
-    knapsack.use_jax = True
-    try:
-        idx = knapsack.solve_arrays(values, sizes, cap)
-    finally:
-        knapsack.use_jax = old
+
+    def numpy_dp_must_not_run(*args):
+        raise AssertionError("solve_arrays fell back to the numpy DP")
+
+    monkeypatch.setattr(knapsack, "use_jax", True)
+    monkeypatch.setattr(knapsack, "_numpy_dp", numpy_dp_must_not_run)
+    idx = knapsack.solve_arrays(values, sizes, cap)
     assert [its[i].name for i in idx] == knapsack.solve_reference(its, cap)
 
 

@@ -1,21 +1,16 @@
-"""Sharding rule tests — pure spec logic over an AbstractMesh (no devices)."""
+"""Sharding rule tests — pure spec logic over an AbstractMesh (no devices),
+plus the shard_map paths (pipeline schedule, embedding gather) on a
+one-device mesh."""
 
 import jax
+import pytest
 from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from repro.distributed import sharding as shd
 
 
-def make_abstract_mesh(shape, names):
-    """AbstractMesh across JAX versions: <=0.4.x takes one
-    ``((name, size), ...)`` shape tuple; >=0.5 takes ``(sizes, names)``."""
-    if jax.__version_info__ >= (0, 5, 0):
-        return AbstractMesh(tuple(shape), tuple(names))
-    return AbstractMesh(tuple(zip(names, shape)))
-
-
-MESH = make_abstract_mesh((16, 16), ("data", "model"))
-MESH3 = make_abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+MESH3 = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def spec_eq(a, b):
@@ -99,3 +94,44 @@ def test_opt_specs_mirror_params():
     ospecs = shd.opt_specs(MESH, oshapes, pshapes, pspecs)
     assert ospecs["mu"]["w"] == pspecs["w"]
     assert spec_eq(ospecs["step"], P())
+
+
+def _one_device_mesh(names):
+    import numpy as np
+    return jax.sharding.Mesh(
+        np.array(jax.devices()[:1]).reshape((1,) * len(names)), names)
+
+
+def test_pipeline_forward_runs_under_shard_map():
+    """One stage, two microbatches: the shard_map schedule applies the
+    layer to every microbatch."""
+    import jax.numpy as jnp
+    import numpy as np
+    from repro.distributed.pipeline import pipeline_forward
+    fn = pipeline_forward(lambda p, x: x * p["w"], 1, 2,
+                          _one_device_mesh(("stage",)))
+    xs = jnp.arange(24.0).reshape(2, 3, 4)
+    out = fn({"w": jnp.full((1, 4), 2.0)}, xs)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(xs) * 2.0)
+
+
+@pytest.mark.parametrize("tied", [True, False])
+def test_embed_lookup_shard_map_matches_take(tied):
+    """With a mesh hint, the shard_map gather (and its custom backward)
+    equals a plain take."""
+    import jax.numpy as jnp
+    import numpy as np
+    from repro.models.common import embed_lookup, set_mesh_hint
+    table = jax.random.normal(jax.random.PRNGKey(0), (16, 8))
+    tokens = jnp.array([[1, 5, 15], [0, 7, 3]], jnp.int32)
+    set_mesh_hint(_one_device_mesh(("data", "model")))
+    try:
+        out = embed_lookup(table, tokens, tied=tied)
+        grad = jax.grad(lambda t: embed_lookup(t, tokens, tied=tied).sum())(
+            table)
+    finally:
+        set_mesh_hint(None)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(jnp.take(table, tokens, axis=0)))
+    want = jax.grad(lambda t: jnp.take(t, tokens, axis=0).sum())(table)
+    np.testing.assert_allclose(np.asarray(grad), np.asarray(want))

@@ -11,7 +11,8 @@ import pytest
 from repro.core import PAPER_DRAM_NVM
 from repro.core.data_objects import ObjectRegistry
 from repro.core.mover import SimTierBackend
-from repro.core.partition import auto_partition, partition_object
+from repro.core.partition import (auto_partition, partition_object,
+                                  partition_object_spans)
 from repro.core.phase import build_phase_graph
 from repro.data import DataConfig, SyntheticTokenPipeline
 
@@ -108,6 +109,39 @@ def test_partition_object_splits_sizes_and_payload():
     np.testing.assert_array_equal(np.asarray(total), np.arange(1000))
 
 
+def _pytree_object(reg):
+    """A 3-leaf pytree object with its leaf spans, as register() makes it."""
+    tree = {"a": jnp.ones((4, 64), jnp.float32),        # 1024 B
+            "b": jnp.full((2, 64), 2.0, jnp.float32),   # 512 B
+            "c": jnp.full((4, 64), 3.0, jnp.float32)}   # 1024 B
+    obj = reg.alloc("tree", 2560, chunkable=True, payload=tree)
+    obj.leaf_spans = [("['a']", 0, 1024), ("['b']", 1024, 512),
+                      ("['c']", 1536, 1024)]
+    return tree
+
+
+def test_partition_pytree_at_leaf_edges_carries_whole_leaves():
+    reg = ObjectRegistry()
+    tree = _pytree_object(reg)
+    chunks = partition_object_spans(reg, "tree", [1024, 2560])
+    assert [c.size_bytes for c in chunks] == [1024, 1536]
+    assert [id(x) for x in chunks[0].payload] == [id(tree["a"])]
+    assert [id(x) for x in chunks[1].payload] == [id(tree["b"]),
+                                                  id(tree["c"])]
+
+
+def test_partition_never_makes_payload_less_chunks():
+    """A cut inside a leaf cannot carry the payload: the object stays
+    whole rather than becoming logical (payload-free) chunks."""
+    reg = ObjectRegistry()
+    _pytree_object(reg)
+    assert partition_object(reg, "tree", 1000) == [reg["tree"]]
+    assert reg["tree"].payload is not None and reg["tree"].parent is None
+    reg.alloc("odd", 300, chunkable=True,
+              payload=jnp.ones((2, 150), jnp.uint8))     # 2-D, no spans
+    assert partition_object(reg, "odd", 100) == [reg["odd"]]
+
+
 def test_auto_partition_only_chunkable_oversize():
     reg = ObjectRegistry()
     reg.alloc("big_chunkable", 100 * MB, chunkable=True)
@@ -136,3 +170,25 @@ def test_sim_mover_overlap_semantics():
     assert backend.wait(h) == pytest.approx(0.5)   # half the copy remains
     clock["t"] = 2.0
     assert backend.wait(h) == 0.0                  # fully overlapped
+
+
+# ---------------------------------------------------------- compile cache
+def test_compile_cache_dir_from_env_or_fixed_repo_path(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR, when set, is left to JAX; otherwise the
+    cache goes to <repo>/.jax_cache, a fixed path that git ignores."""
+    from pathlib import Path
+    from repro.launch import compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
+    assert compile_cache.enable_compile_cache() == "/some/dir"
+    assert jax.config.jax_compilation_cache_dir == before
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    repo = Path(__file__).resolve().parents[1]
+    try:
+        path = compile_cache.enable_compile_cache()
+        assert path == str(repo / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    ignored = (repo / ".gitignore").read_text().split()
+    assert ".jax_cache/" in ignored
