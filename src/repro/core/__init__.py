@@ -32,8 +32,7 @@ from .tenancy import (TENANT_SEP, TenantHandle, TenantSpec, apportion,
                       capacity_shares, channel_shares, per_tenant_p99,
                       tenant_of)
 from .tiers import (MachineProfile, TierSpec, PROFILES, PAPER_DRAM_NVM,
-                    STT_RAM, PCRAM, RERAM, TPU_V5E, TPU_V5E_VMEM,
-                    V5E_PEAK_FLOPS_BF16, V5E_HBM_BW, V5E_ICI_BW)
+                    STT_RAM, PCRAM, RERAM, TPU_V5E, TPU_V5E_VMEM)
 
 __all__ = [
     "DataObject", "ObjectRegistry", "Histogram", "uniform_mass",
@@ -62,5 +61,4 @@ __all__ = [
     "RuntimeConfig", "UnimemRuntime",
     "MachineProfile", "TierSpec", "PROFILES", "PAPER_DRAM_NVM", "STT_RAM",
     "PCRAM", "RERAM", "TPU_V5E", "TPU_V5E_VMEM",
-    "V5E_PEAK_FLOPS_BF16", "V5E_HBM_BW", "V5E_ICI_BW",
 ]

@@ -55,10 +55,12 @@ protocol):
   blocking past the bound (simulated backends compare the remaining
   virtual stall against the timeout; real backends poll readiness against
   a wall-clock deadline).  With ``timeout=None`` the legacy blocking
-  behavior is preserved.  ``wait``/``complete`` raise
-  :class:`~.faults.CopyFailedError` for a copy that errored at land time —
-  the tier never flips, so a failed eviction's residency rolls back and a
-  failed fetch demotes to slow-tier service.
+  behavior is preserved.  It returns the seconds the fence cost: the
+  virtual stall on simulated backends, the wall time the host blocked on
+  real ones (0.0 when the copy had already landed).  ``wait``/``complete``
+  raise :class:`~.faults.CopyFailedError` for a copy that errored at land
+  time — the tier never flips, so a failed eviction's residency rolls back
+  and a failed fetch demotes to slow-tier service.
 * Backends with in-flight semantics additionally expose ``settle(now)``
   (land finished copies without blocking), ``complete(handle)``,
   ``is_done(handle)``, and optionally ``cancel(handle)`` (abort an
@@ -91,6 +93,7 @@ from .faults import (ChannelHealth, CopyError, CopyTimeoutError,
                      DegradedServe, EvictionRollback, TransientCopyError)
 from .phase import PhaseGraph
 from .planner import MoveOp, PlacementPlan, ScheduledMove
+from .spans import span
 from .tenancy import tenant_of
 from .tiers import MachineProfile
 
@@ -140,28 +143,34 @@ class JaxTierBackend:
         return moved
 
     @staticmethod
-    def _wait_leaves(leaves, timeout: Optional[float], what: str) -> None:
-        """Fence leaves; with a timeout, poll readiness against a
-        wall-clock deadline instead of blocking (bounded-wait contract)."""
+    def _wait_leaves(leaves, timeout: Optional[float], what: str) -> float:
+        """Fence leaves and return the seconds the host blocked (0.0 when
+        every leaf was already ready); with a timeout, poll readiness
+        against a wall-clock deadline instead of blocking (bounded-wait
+        contract)."""
+        pending = [l for l in leaves if not l.is_ready()]
+        if not pending:
+            return 0.0
+        t0 = _time.perf_counter()
         if timeout is None:
-            for leaf in leaves:
+            for leaf in pending:
                 leaf.block_until_ready()
-            return
+            return _time.perf_counter() - t0
         deadline = _time.monotonic() + timeout
-        pending = list(leaves)
         while True:
             pending = [l for l in pending if not l.is_ready()]
             if not pending:
-                return
+                return _time.perf_counter() - t0
             if _time.monotonic() >= deadline:
                 raise CopyTimeoutError(
                     f"{what}: {len(pending)} leaves still not ready after "
                     f"{timeout:.3f}s")
             _time.sleep(min(1e-3, timeout / 10))
 
-    def wait(self, handle: Any, timeout: Optional[float] = None) -> None:
-        if handle:
-            self._wait_leaves(handle, timeout, "device_put fence")
+    def wait(self, handle: Any, timeout: Optional[float] = None) -> float:
+        if not handle:
+            return 0.0
+        return self._wait_leaves(handle, timeout, "device_put fence")
 
 
 # ---------------------------------------------------------------------------
@@ -173,6 +182,7 @@ class _AsyncJaxCopy:
     dst: str
     leaves: List[Any]
     landed: bool = False
+    waited_s: float = 0.0       # host seconds a chained fetch blocked on it
 
 
 class AsyncJaxTierBackend(JaxTierBackend):
@@ -194,6 +204,8 @@ class AsyncJaxTierBackend(JaxTierBackend):
 
     ``landed_copies`` (per destination tier) and ``landed_bytes`` count the
     copies that landed; every one of them moved a payload.
+    ``chain_wait_s`` counts the host seconds :meth:`start_move` blocked on
+    the eviction a fetch was chained after.
     """
 
     def __init__(self, machine: MachineProfile):
@@ -201,6 +213,7 @@ class AsyncJaxTierBackend(JaxTierBackend):
         self._open: List[_AsyncJaxCopy] = []
         self.landed_copies: Dict[str, int] = {"fast": 0, "slow": 0}
         self.landed_bytes = 0
+        self.chain_wait_s = 0.0
 
     def start_move(self, obj: DataObject, dst: str,
                    after: Optional[_AsyncJaxCopy] = None) -> Any:
@@ -209,8 +222,9 @@ class AsyncJaxTierBackend(JaxTierBackend):
         # incoming and outgoing bytes (an OOM risk when the fast tier is
         # sized near capacity), so fence the predecessor's leaves first.
         if after is not None and not getattr(after, "landed", True):
-            for leaf in after.leaves:
-                leaf.block_until_ready()
+            with span("chain_wait", obj=after.obj.name):
+                after.waited_s = self._wait_leaves(after.leaves, None, "")
+            self.chain_wait_s += after.waited_s
             self._land(after)
         tier = self.machine.fast if dst == "fast" else self.machine.slow
         kind = tier.memory_kind
@@ -241,11 +255,12 @@ class AsyncJaxTierBackend(JaxTierBackend):
 
     def wait(self, handle: Optional[_AsyncJaxCopy],
              timeout: Optional[float] = None) -> float:
-        if handle is not None:
-            self._wait_leaves(handle.leaves, timeout,
-                              f"async copy of {handle.obj.name}")
-            self._land(handle)
-        return 0.0              # real backend: the fence blocked, no stall
+        if handle is None:
+            return 0.0
+        blocked = self._wait_leaves(handle.leaves, timeout,
+                                    f"async copy of {handle.obj.name}")
+        self._land(handle)
+        return blocked
 
     def complete(self, handle: Optional[_AsyncJaxCopy]) -> None:
         self.wait(handle)
@@ -333,16 +348,22 @@ class CpuPoolBackend:
 
     def wait(self, handle: Optional[_PoolCopy],
              timeout: Optional[float] = None) -> float:
-        if handle is not None:
-            import concurrent.futures
+        """Fence one copy; returns the seconds the host blocked."""
+        if handle is None:
+            return 0.0
+        import concurrent.futures
+        blocked = 0.0
+        if not handle.future.done():
+            t0 = _time.perf_counter()
             try:
                 handle.future.result(timeout=timeout)
             except concurrent.futures.TimeoutError:
                 raise CopyTimeoutError(
                     f"pool copy of {handle.obj.name} still running after "
                     f"{timeout:.3f}s") from None
-            self._land(handle)
-        return 0.0                      # real backend: the fence blocked
+            blocked = _time.perf_counter() - t0
+        self._land(handle)
+        return blocked
 
     def complete(self, handle: Optional[_PoolCopy]) -> None:
         self.wait(handle)
@@ -827,14 +848,16 @@ class ProactiveMover:
 
     def on_phase_start(self, plan: PlacementPlan, phase_index: int,
                        n_phases: int) -> float:
-        """Fence + trigger.  Returns fence stall seconds (sim backend) or 0."""
+        """Fence + trigger.  Returns the fence stall: virtual seconds on a
+        simulated backend, the host's blocked seconds on a real one."""
         stall = 0.0
         # 1. fence
         for m in plan.fences_for_phase(phase_index):
             h = self._inflight.pop(m.obj, None)
             if h is not None:
                 try:
-                    s = self.backend.wait(h)
+                    with span("fence_wait", obj=m.obj, bytes=m.size_bytes):
+                        s = self.backend.wait(h)
                 except CopyError:
                     # the copy never delivered: a fetch serves slow this
                     # iteration, a failed eviction keeps its residency
@@ -862,7 +885,8 @@ class ProactiveMover:
             self.stats.moved_bytes += m.size_bytes
             if m.needed_by == phase_index:
                 try:
-                    s = self.backend.wait(h)
+                    with span("fence_wait", obj=m.obj, bytes=m.size_bytes):
+                        s = self.backend.wait(h)
                 except CopyError:
                     self._fault(m, phase_index, "late_fail",
                                 getattr(h, "channel", -1))
@@ -882,7 +906,9 @@ class ProactiveMover:
         attempts = 0
         while True:
             try:
-                return self.backend.start_move(obj, m.dst)
+                with span("copy_start", obj=obj.name, bytes=obj.size_bytes,
+                          dst=m.dst):
+                    return self.backend.start_move(obj, m.dst)
             except TransientCopyError:
                 attempts += 1
                 if attempts > self.retry_limit:
@@ -893,7 +919,8 @@ class ProactiveMover:
     def drain(self) -> None:
         for obj, h in list(self._inflight.items()):
             try:
-                self.backend.wait(h)
+                with span("fence_wait", obj=obj):
+                    self.backend.wait(h)
             except CopyError:
                 pass                # draining: the copy's fate is recorded
             del self._inflight[obj]
@@ -940,8 +967,10 @@ class SlackAwareMover:
 
     Works against any :class:`TierBackend`; the timing-aware paths activate
     when the backend exposes the simulator's ``settle``/``complete``/``done``
-    surface (blocking backends such as :class:`JaxTierBackend` fence with
-    zero recorded stall, exactly like :class:`ProactiveMover`).
+    surface.  Blocking backends such as :class:`JaxTierBackend` fence one
+    copy after another and record the host seconds each ``wait`` blocked,
+    plus the seconds :class:`AsyncJaxTierBackend` blocked on an eviction
+    a fetch was chained after (its ``chain_wait_s``).
     """
 
     def __init__(self, registry: ObjectRegistry, backend: TierBackend,
@@ -1073,22 +1102,23 @@ class SlackAwareMover:
     def _start_move_raw(self, obj: DataObject, dst: str,
                         after: Any = None, avoid: Optional[set] = None,
                         prefer: Optional[frozenset] = None) -> Any:
-        if prefer:
+        with span("copy_start", obj=obj.name, bytes=obj.size_bytes, dst=dst):
+            if prefer:
+                try:
+                    if avoid:
+                        return self.backend.start_move(
+                            obj, dst, after=after, avoid=avoid, prefer=prefer)
+                    return self.backend.start_move(obj, dst, after=after,
+                                                   prefer=prefer)
+                except TypeError:   # backend without tenant preference
+                    pass
             try:
                 if avoid:
                     return self.backend.start_move(obj, dst, after=after,
-                                                   avoid=avoid, prefer=prefer)
-                return self.backend.start_move(obj, dst, after=after,
-                                               prefer=prefer)
-            except TypeError:   # backend without tenant channel preference
-                pass
-        try:
-            if avoid:
-                return self.backend.start_move(obj, dst, after=after,
-                                               avoid=avoid)
-            return self.backend.start_move(obj, dst, after=after)
-        except TypeError:       # backend without dependency chaining
-            return self.backend.start_move(obj, dst)
+                                                   avoid=avoid)
+                return self.backend.start_move(obj, dst, after=after)
+            except TypeError:       # backend without dependency chaining
+                return self.backend.start_move(obj, dst)
 
     def _start_with_retry(self, entry: ScheduledMove, obj: DataObject,
                           after: Any, now: float) -> Optional[Any]:
@@ -1204,7 +1234,8 @@ class SlackAwareMover:
                         self._fail_inflight(m.obj, h, phase_index,
                                             "late_fail", now)
                         continue
-                    self.stats.overlapped_moves += 1
+                    # overlapped unless a chained fetch blocked on it
+                    self._count_fence(getattr(h, "waited_s", 0.0))
                     self.health.record_success(getattr(h, "channel", -1))
                     self._finish_record(m.obj, now, 0.0)
                 continue
@@ -1215,23 +1246,21 @@ class SlackAwareMover:
             else:
                 singles.append((m, h))
 
-        stall = 0.0
+        stall = 0.0         # timed copies run on parallel channels: the max
+        blocked = 0.0       # blocking waits run one after another: the sum
         for m, h in singles:
             done = self._done_of(h)
             if done is None:
                 # blocking backend (real arrays): the fence must block
                 # here — but never past the straggler deadline
                 try:
-                    self.backend.wait(h, timeout=self._deadline_for(
-                        m.size_bytes))
-                except TypeError:
-                    self.backend.wait(h)
+                    s = self._blocking_wait(m, h)
                 except CopyError:
                     self._cancel(h)
                     self._fail_inflight(m.obj, h, phase_index,
                                         "deadline", now)
                     continue
-                s = 0.0
+                blocked += s
             else:
                 s = max(0.0, done - now)
                 if self._service_exceeded(h, self._deadline_for(m.size_bytes)):
@@ -1241,8 +1270,8 @@ class SlackAwareMover:
                     self._fail_inflight(m.obj, h, phase_index,
                                         "deadline", now)
                     continue
-            # parallel channels: waiting on all fenced copies costs the max
-            stall = max(stall, s)
+                # parallel channels: waiting on all fenced copies costs the max
+                stall = max(stall, s)
             self._count_fence(s)
             try:
                 self._complete(h)
@@ -1252,6 +1281,8 @@ class SlackAwareMover:
             self.health.record_success(getattr(h, "channel", -1))
             self._finish_record(m.obj, now, s)
 
+        if blocked:
+            stall += blocked
         phase_est = (self.graph[phase_index].time
                      if self.graph is not None else 0.0)
         t0 = now + stall
@@ -1286,16 +1317,12 @@ class SlackAwareMover:
             done = self._done_of(h)
             if done is None:
                 try:    # blocking backend: fence the chunk (bounded)
-                    self.backend.wait(h, timeout=self._deadline_for(
-                        m.size_bytes))
-                except TypeError:
-                    self.backend.wait(h)
+                    late = self._blocking_wait(m, h)
                 except CopyError:
                     self._cancel(h)
                     self._fail_inflight(m.obj, h, phase_index,
                                         "deadline", consume)
                     continue
-                late = 0.0
             else:
                 late = max(0.0, done - consume)
                 if self._service_exceeded(h, self._deadline_for(m.size_bytes)):
@@ -1315,6 +1342,17 @@ class SlackAwareMover:
             self.health.record_success(getattr(h, "channel", -1))
             self._finish_record(m.obj, consume, late)
         return extra
+
+    def _blocking_wait(self, m: MoveOp, h: Any) -> float:
+        """Fence one copy on a blocking backend, never past the straggler
+        deadline; returns the seconds the host blocked."""
+        with span("fence_wait", obj=m.obj, bytes=m.size_bytes):
+            try:
+                s = self.backend.wait(h, timeout=self._deadline_for(
+                    m.size_bytes))
+            except TypeError:
+                s = self.backend.wait(h)
+        return float(s) if isinstance(s, (int, float)) else 0.0
 
     def _finish_record(self, obj: str, fenced_at: float, stall: float,
                        superseded: bool = False) -> None:
@@ -1392,20 +1430,28 @@ class SlackAwareMover:
         now = self._now()
         settle = getattr(self.backend, "settle", None)
         if settle is not None:
-            settle(now)
+            with span("settle"):
+                settle(now)
         # failure upkeep (both no-ops on a fault-free run): purge copies
         # that late-failed at settle, then cancel-and-reissue stragglers
         self._sweep_failures(phase_index, now)
         self._detect_stragglers(phase_index, now)
         # release first so moves this phase both triggers AND consumes flow
         # through the same fence logic (incl. chunk-granular consumption)
+        chained0 = getattr(self.backend, "chain_wait_s", 0.0)
         self._release(plan, phase_index, n_phases, now)
-        return self._fence(plan, phase_index, now)
+        chained = getattr(self.backend, "chain_wait_s", 0.0) - chained0
+        stall = self._fence(plan, phase_index, now)
+        if chained:     # the host blocked on evictions before fetches
+            self.stats.fence_stall_s += chained
+            stall += chained
+        return stall
 
     def drain(self) -> None:
         for name, h in list(self._inflight.items()):
             try:
-                self.backend.wait(h)
+                with span("fence_wait", obj=name):
+                    self.backend.wait(h)
                 self._complete(h)
             except CopyError:
                 pass            # draining: the copy's fate is recorded

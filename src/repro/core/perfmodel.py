@@ -206,7 +206,7 @@ class LinkSpec:
     """One directed interconnect link between two hosts.
 
     ``bandwidth`` is the sustained point-to-point rate in bytes/s (e.g.
-    ``tiers.V5E_ICI_BW`` for on-pod ICI, ~25-50x less for DCN);
+    about 50 GB/s for a v5e's on-pod ICI, ~25-50x less for DCN);
     ``latency`` the per-transfer setup cost in seconds (rendezvous +
     first-byte); ``channel_pairs`` how many concurrent send/recv pairs
     the link sustains at full rate (transfers beyond that queue)."""

@@ -59,6 +59,7 @@ from .perfmodel import CalibrationConstants
 from .phase import Phase, PhaseGraph, PhaseTraceEvent
 from .planner import MoveOp, PlacementPlan, Planner, emit_schedule
 from .profiler import PhaseProfiler
+from .spans import span
 from .tenancy import TenantHandle, TenantSpec, tenant_of
 from .tiers import MachineProfile
 
@@ -272,6 +273,7 @@ class Session:
         self._static_refs: Dict[str, float] = {}
         self.n_replans = 0              # drift-triggered replan cycles
         self.n_incremental_replans = 0  # ... served without dropping the plan
+        self.plan_s = 0.0               # host seconds in ``unimem.plan``
         # Calibration feedback state: per-iteration measurement
         # accumulators, the per-plan-epoch correction budget, and the flag
         # that invalidates standing-plan reuse after a CF change (a cf
@@ -508,7 +510,8 @@ class Session:
             self._open_phase = None
             self._events_this_iter = []
             raise
-        self._end_iteration()
+        with span("iteration_exit"):
+            self._end_iteration()
 
     @contextlib.contextmanager
     def phase(self, name, *, accesses: Optional[Dict[str, float]] = None,
@@ -543,7 +546,8 @@ class Session:
             index = self._phase_id(name)
         pname = self._phase_names[index]
         self._open_phase = pname
-        stall = self._phase_begin(index)
+        with span("phase_enter", phase=pname):
+            stall = self._phase_begin(index)
         ctx = PhaseContext(name=pname, index=index, stall_s=stall)
         t0 = _time.perf_counter()
         try:
@@ -552,25 +556,26 @@ class Session:
             self._open_phase = None
             raise
         wall = _time.perf_counter() - t0
-        sample = None
-        if self.source is not None:
-            # per-field precedence: explicit keyword > source > measured
-            # (an explicit accesses override must not silently discard the
-            # source's virtual elapsed or its access_bins)
-            sample = self.source.collect(pname)
-            if accesses is None:
-                accesses = sample.accesses
-            if time_shares is None:
-                time_shares = sample.time_shares
-            if access_bins is None:
-                access_bins = sample.access_bins
-            if elapsed is None:
-                elapsed = sample.elapsed
-        ctx.elapsed = elapsed if elapsed is not None else wall
-        ctx.sample = sample
-        self._open_phase = None
-        self._phase_end(index, elapsed=ctx.elapsed, accesses=accesses,
-                        time_shares=time_shares, access_bins=access_bins)
+        with span("phase_exit", phase=pname):
+            sample = None
+            if self.source is not None:
+                # per-field precedence: explicit keyword > source > measured
+                # (an explicit accesses override must not silently discard
+                # the source's virtual elapsed or its access_bins)
+                sample = self.source.collect(pname)
+                if accesses is None:
+                    accesses = sample.accesses
+                if time_shares is None:
+                    time_shares = sample.time_shares
+                if access_bins is None:
+                    access_bins = sample.access_bins
+                if elapsed is None:
+                    elapsed = sample.elapsed
+            ctx.elapsed = elapsed if elapsed is not None else wall
+            ctx.sample = sample
+            self._open_phase = None
+            self._phase_end(index, elapsed=ctx.elapsed, accesses=accesses,
+                            time_shares=time_shares, access_bins=access_bins)
 
     # ------------------------------------------------------------- main loop
     def _begin_iteration(self) -> None:
@@ -594,8 +599,8 @@ class Session:
 
     def _phase_begin(self, index: int) -> float:
         """Enter phase ``index``: fence + trigger proactive moves.  Returns
-        the fence stall in seconds (simulated backends) — real backends
-        block and return 0.
+        the fence stall in seconds: virtual on simulated backends, the
+        host's blocked wall time on real ones.
 
         The mover is driven with the phase count the plan was *built*
         against, not the live one: auto-registration can grow the phase
@@ -839,54 +844,65 @@ class Session:
                          if self._drift_scope is not None
                          and standing is not None else None))
 
+    @contextlib.contextmanager
+    def _planning(self, reason: str):
+        """Span ``unimem.plan`` and count its host seconds in ``plan_s``."""
+        t0 = _time.perf_counter()
+        try:
+            with span("plan", reason=reason):
+                yield
+        finally:
+            self.plan_s += _time.perf_counter() - t0
+
     def _build_plan(self, *, recalibration: bool = False) -> None:
-        assert self.graph is not None
-        self.plan = self.policy.build(self._pipeline_state())
-        self._drift_scope = None
-        self._cf_dirty = False
-        if self.plan is None:
-            return
-        if (self.config.host is not None
-                and isinstance(self.plan, policy_mod.PlanProgram)):
-            self.plan.host = self.config.host
-        if ((self._degraded_since_plan or self._rollbacks_since_plan)
-                and isinstance(self.plan, policy_mod.PlanProgram)):
-            # fault-bearing rebuild: stamp the provenance (an *extra*
-            # entry — the canonical stage list is untouched)
-            self.plan.provenance.append(policy_mod.fault_provenance(
-                self._degraded_since_plan, self._rollbacks_since_plan,
-                self.profiler.epoch, self.registry.generation,
-                hist_epoch=getattr(self.profiler, "hist_epoch", 0)))
-        self._degraded_since_plan = 0
-        self._rollbacks_since_plan = 0
-        # Admission-control provenance: every tenant the bandwidth
-        # partition demoted to serve-from-slow this epoch gets a
-        # DegradedServe entry (phase -1 = whole-tenant, not one fetch).
-        # Logged directly — not via mover fault_events — so the chaos
-        # counters and the fault-triggered audit stay untouched.
-        for t, why in sorted(
-                (getattr(self.plan, "tenant_admission", None) or {}).items()):
-            self.n_admission_demotions += 1
-            self.fault_log.append(DegradedServe(
-                obj=t, phase_index=-1, reason=f"admission:{why}",
-                iteration=self._iteration, tenant=t,
-                host=self.config.host))
-        if not recalibration:
-            # a profiling-driven build opens a new plan epoch: re-arm the
-            # calibration-correction budget and the best-measured memory
-            self._cal_rounds_left = self.config.calibration_rounds
-            self._cal_best = None
-            self._cal_snapshot = None
-        self._measure_pending = True
-        self._plan_n_phases = len(self._phase_names)
-        self._baseline_pending = True
-        self.monitor.consume_events()
-        # Enact iteration-start moves for the new plan immediately.
-        if self.mover is not None:
-            if hasattr(self.mover, "load_plan"):
-                self.mover.load_plan(self.plan, self.graph)
-            self.mover.on_phase_start(self.plan, 0, self._plan_n_phases)
-            self._drain_mover_faults()
+        with self._planning("recalibration" if recalibration else "profile"):
+            assert self.graph is not None
+            self.plan = self.policy.build(self._pipeline_state())
+            self._drift_scope = None
+            self._cf_dirty = False
+            if self.plan is None:
+                return
+            if (self.config.host is not None
+                    and isinstance(self.plan, policy_mod.PlanProgram)):
+                self.plan.host = self.config.host
+            if ((self._degraded_since_plan or self._rollbacks_since_plan)
+                    and isinstance(self.plan, policy_mod.PlanProgram)):
+                # fault-bearing rebuild: stamp the provenance (an *extra*
+                # entry — the canonical stage list is untouched)
+                self.plan.provenance.append(policy_mod.fault_provenance(
+                    self._degraded_since_plan, self._rollbacks_since_plan,
+                    self.profiler.epoch, self.registry.generation,
+                    hist_epoch=getattr(self.profiler, "hist_epoch", 0)))
+            self._degraded_since_plan = 0
+            self._rollbacks_since_plan = 0
+            # Admission-control provenance: every tenant the bandwidth
+            # partition demoted to serve-from-slow this epoch gets a
+            # DegradedServe entry (phase -1 = whole-tenant, not one fetch).
+            # Logged directly — not via mover fault_events — so the chaos
+            # counters and the fault-triggered audit stay untouched.
+            admitted = getattr(self.plan, "tenant_admission", None) or {}
+            for t, why in sorted(admitted.items()):
+                self.n_admission_demotions += 1
+                self.fault_log.append(DegradedServe(
+                    obj=t, phase_index=-1, reason=f"admission:{why}",
+                    iteration=self._iteration, tenant=t,
+                    host=self.config.host))
+            if not recalibration:
+                # a profiling-driven build opens a new plan epoch: re-arm the
+                # calibration-correction budget and the best-measured memory
+                self._cal_rounds_left = self.config.calibration_rounds
+                self._cal_best = None
+                self._cal_snapshot = None
+            self._measure_pending = True
+            self._plan_n_phases = len(self._phase_names)
+            self._baseline_pending = True
+            self.monitor.consume_events()
+            # Enact iteration-start moves for the new plan immediately.
+            if self.mover is not None:
+                if hasattr(self.mover, "load_plan"):
+                    self.mover.load_plan(self.plan, self.graph)
+                self.mover.on_phase_start(self.plan, 0, self._plan_n_phases)
+                self._drain_mover_faults()
 
     def _on_baseline_measured(self, measured: float) -> None:
         """Calibration feedback — the live extension of
@@ -1029,37 +1045,38 @@ class Session:
         mid-iteration each time around, a permanent thrash cycle the plan
         never asked for.  ``est_unhidden_cost`` stays 0 because they are
         one-time reconciliation moves, not per-iteration plan cost."""
-        assert self.graph is not None
-        want0 = plan.residents[0] if plan.residents else set()
-        corrective: List[MoveOp] = []
-        for obj in self.registry:
-            if obj.pinned:
-                continue
-            if obj.name in want0:
-                if obj.tier != "fast":
+        with self._planning("restore"):
+            assert self.graph is not None
+            want0 = plan.residents[0] if plan.residents else set()
+            corrective: List[MoveOp] = []
+            for obj in self.registry:
+                if obj.pinned:
+                    continue
+                if obj.name in want0:
+                    if obj.tier != "fast":
+                        corrective.append(
+                            MoveOp(obj.name, "fast", 0, 0, obj.size_bytes))
+                elif obj.tier == "fast":
                     corrective.append(
-                        MoveOp(obj.name, "fast", 0, 0, obj.size_bytes))
-            elif obj.tier == "fast":
-                corrective.append(
-                    MoveOp(obj.name, "slow", 0, 0, obj.size_bytes))
-        enact = plan
-        if corrective:
-            enact = dataclasses.replace(
-                plan, moves=list(plan.moves) + corrective,
-                schedule=(list(plan.schedule) + emit_schedule(
-                    corrective, self.graph, self.machine.copy_bw)
-                    if plan.schedule else []))
-        self.plan = plan
-        self._drift_scope = None
-        self._measure_pending = True
-        self._plan_n_phases = len(self._phase_names)
-        self._baseline_pending = True
-        self.monitor.consume_events()
-        if self.mover is not None:
-            if hasattr(self.mover, "load_plan"):
-                self.mover.load_plan(enact, self.graph)
-            self.mover.on_phase_start(enact, 0, self._plan_n_phases)
-            self._drain_mover_faults()
+                        MoveOp(obj.name, "slow", 0, 0, obj.size_bytes))
+            enact = plan
+            if corrective:
+                enact = dataclasses.replace(
+                    plan, moves=list(plan.moves) + corrective,
+                    schedule=(list(plan.schedule) + emit_schedule(
+                        corrective, self.graph, self.machine.copy_bw)
+                        if plan.schedule else []))
+            self.plan = plan
+            self._drift_scope = None
+            self._measure_pending = True
+            self._plan_n_phases = len(self._phase_names)
+            self._baseline_pending = True
+            self.monitor.consume_events()
+            if self.mover is not None:
+                if hasattr(self.mover, "load_plan"):
+                    self.mover.load_plan(enact, self.graph)
+                self.mover.on_phase_start(enact, 0, self._plan_n_phases)
+                self._drain_mover_faults()
 
     def _reprofile(self) -> None:
         """Drift response.  Incremental (default): keep serving the current
@@ -1075,48 +1092,51 @@ class Session:
         knapsacks instead of O(plan).  A phase that drifted without
         tripping the monitor is caught on the next cycle (its post-replan
         baseline re-arms the monitor)."""
-        self.n_replans += 1
-        if self.config.incremental_replan and self.plan is not None:
-            self.n_incremental_replans += 1
-            drifted = set(self.monitor.drifted_phases())
-            scope = None
-            if (self.config.scoped_replan and drifted
-                    and isinstance(self.plan, policy_mod.PlanProgram)):
-                scope = drifted
-            self._drift_scope = scope
-            self.profiler.decay(
-                self.config.replan_decay,
-                phases=sorted(scope) if scope is not None else None)
-            if self.config.histogram_refine:
-                # refine before the re-profiling window opens so the
-                # re-observed iterations sample into the adapted bins (a
-                # re-heated region's bins split; the re-split pass can
-                # then cut below the old coarse ceiling at rebuild)
-                self.profiler.refine_histograms(
-                    self.config.histogram_bins,
+        with self._planning("drift"):
+            self.n_replans += 1
+            if self.config.incremental_replan and self.plan is not None:
+                self.n_incremental_replans += 1
+                drifted = set(self.monitor.drifted_phases())
+                scope = None
+                if (self.config.scoped_replan and drifted
+                        and isinstance(self.plan, policy_mod.PlanProgram)):
+                    scope = drifted
+                self._drift_scope = scope
+                self.profiler.decay(
+                    self.config.replan_decay,
                     phases=sorted(scope) if scope is not None else None)
-            self._profiling = True
-            self._profiled_iters = 0
-        else:
-            self._drift_scope = None
-            self.profiler.clear()
-            self._profiling = True
-            self._profiled_iters = 0
-            self.plan = None
-            self._iteration = 0
-        # Drift fires mid-iteration: the phases already executed this
-        # iteration (including the drifted one) were routed to the monitor,
-        # not the profiler — replay them so the re-profiling window covers
-        # the full iteration, not just the phases after the drift.
-        for ev in self._events_this_iter:
-            if (self._drift_scope is not None
-                    and ev.phase_index not in self._drift_scope
-                    and self._access_set_drifted(ev)):
-                self._drift_scope.add(ev.phase_index)
-                self.profiler.decay(self.config.replan_decay,
-                                    phases=[ev.phase_index])
-            if self._drift_scope is None or ev.phase_index in self._drift_scope:
-                self.profiler.observe(ev)
+                if self.config.histogram_refine:
+                    # refine before the re-profiling window opens so the
+                    # re-observed iterations sample into the adapted bins (a
+                    # re-heated region's bins split; the re-split pass can
+                    # then cut below the old coarse ceiling at rebuild)
+                    self.profiler.refine_histograms(
+                        self.config.histogram_bins,
+                        phases=sorted(scope) if scope is not None else None)
+                self._profiling = True
+                self._profiled_iters = 0
+            else:
+                self._drift_scope = None
+                self.profiler.clear()
+                self._profiling = True
+                self._profiled_iters = 0
+                self.plan = None
+                self._iteration = 0
+            # Drift fires mid-iteration: the phases already executed this
+            # iteration (including the drifted one) were routed to the
+            # monitor, not the profiler — replay them so the re-profiling
+            # window covers the full iteration, not just the phases after
+            # the drift.
+            for ev in self._events_this_iter:
+                if (self._drift_scope is not None
+                        and ev.phase_index not in self._drift_scope
+                        and self._access_set_drifted(ev)):
+                    self._drift_scope.add(ev.phase_index)
+                    self.profiler.decay(self.config.replan_decay,
+                                        phases=[ev.phase_index])
+                if (self._drift_scope is None
+                        or ev.phase_index in self._drift_scope):
+                    self.profiler.observe(ev)
 
     def _access_set_drifted(self, ev: PhaseTraceEvent) -> bool:
         """Access-mix drift the time-based monitor cannot see: an object
@@ -1164,6 +1184,7 @@ class Session:
             n_objects=len(self.registry),
             n_replans=self.n_replans,
             n_incremental_replans=self.n_incremental_replans,
+            plan_s=self.plan_s,
             measured_iteration_time=self.last_measured_iteration_time,
             pred_err=self.last_pred_err,
             cf_bw=self.cf.cf_bw,

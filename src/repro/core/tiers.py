@@ -138,8 +138,3 @@ TPU_V5E_VMEM = MachineProfile(
 
 PROFILES = {p.name: p for p in
             [PAPER_DRAM_NVM, STT_RAM, PCRAM, RERAM, TPU_V5E, TPU_V5E_VMEM]}
-
-# Roofline hardware constants for TPU v5e (per chip).
-V5E_PEAK_FLOPS_BF16 = 197e12
-V5E_HBM_BW = 819e9
-V5E_ICI_BW = 50e9

@@ -7,6 +7,11 @@ attention / hybrid / xlstm cache families) and integrates the Unimem
 runtime: params and the KV cache are registered as data objects by size
 only (jit owns the buffers), so the runtime profiles and plans them but
 moves none of their bytes.
+
+Each ``generate`` call is a ``unimem.generate`` span, holding the spans of
+its parts (``init_cache``, ``prefill``, ``decode``, ``output``): a step
+loop's span closes on its ``block_until_ready``, so it times the device's
+work, and no span is opened per token.
 """
 
 from __future__ import annotations
@@ -19,6 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ArchConfig
+from ..core.spans import span
 from ..models import lm
 
 
@@ -89,29 +95,32 @@ class ServeEngine:
         """prompts: (B, P) int32.  Returns (B, P + n_new)."""
         B, P = prompts.shape
         assert B == self.batch
-        cache = lm.init_cache(self.cfg, B, self.max_seq)
-        self._register(cache)
-        with (self.runtime.iteration() if self.runtime is not None
-              else contextlib.nullcontext()):
-            tok = prompts[:, 0]
-            out = [prompts]
-            # prefill by scanned decode (uniform across cache families).
-            # Each phase ends on block_until_ready of its last output, so
-            # the runtime times the device's work, not its enqueue.
-            with self._phase("prefill"):
-                for i in range(P):
-                    nxt, _, cache = self.step(self.params, cache,
-                                              prompts[:, i], jnp.int32(i))
-                    self.stats.prefill_tokens += B
-                jax.block_until_ready((nxt, cache))
-            tok = nxt
-            gen = []
-            with self._phase("decode"):
-                for j in range(n_new):
-                    gen.append(tok[:, None])
-                    nxt, _, cache = self.step(self.params, cache, tok,
-                                              jnp.int32(P + j))
-                    tok = nxt
-                    self.stats.decode_tokens += B
-                jax.block_until_ready((tok, cache))
-            return jnp.concatenate(out + gen, axis=1)
+        with span("generate", prompt=P, new_tokens=n_new):
+            with span("init_cache"):
+                cache = lm.init_cache(self.cfg, B, self.max_seq)
+            self._register(cache)
+            with (self.runtime.iteration() if self.runtime is not None
+                  else contextlib.nullcontext()):
+                tok = prompts[:, 0]
+                out = [prompts]
+                # prefill by scanned decode (uniform across cache families).
+                # Each phase ends on block_until_ready of its last output, so
+                # the runtime times the device's work, not its enqueue.
+                with self._phase("prefill"), span("prefill"):
+                    for i in range(P):
+                        nxt, _, cache = self.step(self.params, cache,
+                                                  prompts[:, i], jnp.int32(i))
+                        self.stats.prefill_tokens += B
+                    jax.block_until_ready((nxt, cache))
+                tok = nxt
+                gen = []
+                with self._phase("decode"), span("decode"):
+                    for j in range(n_new):
+                        gen.append(tok[:, None])
+                        nxt, _, cache = self.step(self.params, cache, tok,
+                                                  jnp.int32(P + j))
+                        tok = nxt
+                        self.stats.decode_tokens += B
+                    jax.block_until_ready((tok, cache))
+                with span("output"):
+                    return jnp.concatenate(out + gen, axis=1)

@@ -522,9 +522,11 @@ def test_cpu_pool_backend_moves_and_lands_on_settle():
         assert obj.tier == "fast" and h.landed
         moved = obj.payload["w"]
         assert moved is not src and np.array_equal(moved, src)
-        # wait() fences and lands; logical objects flip immediately
+        # wait() fences, lands and returns the seconds it blocked;
+        # logical objects flip immediately
         o2 = reg.alloc("y", 1024, payload={"w": np.ones(256, np.float32)})
-        assert b.wait(b.start_move(o2, "fast")) == 0.0
+        assert b.wait(b.start_move(o2, "fast")) >= 0.0
+        assert b.wait(h) == 0.0         # landed already: nothing blocked
         assert o2.tier == "fast"
         o3 = reg.alloc("z", 1024)
         assert b.start_move(o3, "fast") is None and o3.tier == "fast"
