@@ -138,33 +138,45 @@ def attn_forward(params: Dict[str, jax.Array], x: jax.Array,
 def attn_decode(params: Dict[str, jax.Array], x: jax.Array,
                 cache_k: jax.Array, cache_v: jax.Array, pos: jax.Array,
                 cos: jax.Array, sin: jax.Array, cfg: ArchConfig,
-                *, kv_chunk: int = 8192
+                *, layer: Optional[jax.Array] = None, kv_chunk: int = 8192
                 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """One-token decode against a KV cache.
 
     x: (B, 1, d); cache_k/v: (B, S_max, K, Dh); pos: scalar current length.
+    With ``layer``, cache_k/v are the whole stacked cache (L, B, S_max, K,
+    Dh): the new entry is written at (layer, :, pos) and the chunks are read
+    from that layer of it, so a layer scan that carries the cache updates
+    it in place.
     Returns (out (B,1,d), new_cache_k, new_cache_v).
     """
     B, _, d = x.shape
     H, K, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
-    S_max = cache_k.shape[1]
-    q = x @ params["wq"]
-    k = x @ params["wk"]
-    v = x @ params["wv"]
-    if cfg.attn_bias:
-        q = q + params["bq"]
-        k = k + params["bk"]
-        v = v + params["bv"]
-    q = q.reshape(B, 1, H, Dh)
-    k = k.reshape(B, 1, K, Dh)
-    v = v.reshape(B, 1, K, Dh)
+    S_max = cache_k.shape[-3]
+    lead = () if layer is None else (layer,)    # index of the layer, if any
+    ones = (1,) * len(lead)
+
+    def project(name):
+        # bias added to the float32 sum and rounded once: what the TPU did
+        # when it fused the add into the matmul, kept now that the in-place
+        # cache write takes the add into its own fusion
+        y = jnp.dot(x, params["w" + name], preferred_element_type=jnp.float32)
+        if cfg.attn_bias:
+            y = y + params["b" + name]
+        return y.astype(x.dtype)
+
+    q = project("q").reshape(B, 1, H, Dh)
+    k = project("k").reshape(B, 1, K, Dh)
+    v = project("v").reshape(B, 1, K, Dh)
     rd = int(Dh * cfg.rotary_fraction)
     if rd:
         pvec = jnp.full((1,), pos, jnp.int32)
         q = apply_rope(q, cos, sin, positions=pvec, rotary_dim=rd)
         k = apply_rope(k, cos, sin, positions=pvec, rotary_dim=rd)
-    cache_k = jax.lax.dynamic_update_slice_in_dim(cache_k, k.astype(cache_k.dtype), pos, axis=1)
-    cache_v = jax.lax.dynamic_update_slice_in_dim(cache_v, v.astype(cache_v.dtype), pos, axis=1)
+    at = lead + (0, pos, 0, 0)
+    cache_k = jax.lax.dynamic_update_slice(
+        cache_k, k.astype(cache_k.dtype).reshape(ones + k.shape), at)
+    cache_v = jax.lax.dynamic_update_slice(
+        cache_v, v.astype(cache_v.dtype).reshape(ones + v.shape), at)
 
     # flash-decoding: stream KV chunks with a running softmax so the score
     # tensor never exceeds (B, K, G, chunk) — bounded at 500k-token caches
@@ -180,8 +192,9 @@ def attn_decode(params: Dict[str, jax.Array], x: jax.Array,
         m, l, acc = carry
         # dynamic slices view the cache in place — no transposed copy of a
         # multi-GiB buffer
-        kb = jax.lax.dynamic_slice_in_dim(cache_k, ci * bk, bk, axis=1)
-        vb = jax.lax.dynamic_slice_in_dim(cache_v, ci * bk, bk, axis=1)
+        start, size = lead + (0, ci * bk, 0, 0), ones + (B, bk, K, Dh)
+        kb = jax.lax.dynamic_slice(cache_k, start, size).reshape(B, bk, K, Dh)
+        vb = jax.lax.dynamic_slice(cache_v, start, size).reshape(B, bk, K, Dh)
         t_pos = ci * bk + jnp.arange(bk)
         s = jnp.einsum("bkgd,btkd->bkgt", qf, kb.astype(jnp.float32))
         s = jnp.where((t_pos > pos)[None, None, None, :], NEG_INF, s)

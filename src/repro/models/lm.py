@@ -29,6 +29,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from ..configs.base import ArchConfig
 from . import attention, mamba2, mlp as mlp_mod, moe as moe_mod, xlstm
@@ -80,10 +81,10 @@ def _attn_block_fwd(block, x, cos, sin, cfg, q_offset=0):
     return x, aux
 
 
-def _attn_block_decode(block, x, ck, cv, pos, cos, sin, cfg):
+def _attn_block_decode(block, x, ck, cv, pos, cos, sin, cfg, layer=None):
     h = _norm(block, "ln1", x, cfg)
     out, ck, cv = attention.attn_decode(block["attn"], h, ck, cv, pos,
-                                        cos, sin, cfg)
+                                        cos, sin, cfg, layer=layer)
     x = x + out
     h = _norm(block, "ln2", x, cfg)
     if cfg.is_moe:
@@ -307,6 +308,16 @@ def init_cache(cfg: ArchConfig, batch: int, max_seq: int,
     return cache
 
 
+def _argument_layout(x: jax.Array) -> jax.Array:
+    """Pin ``x`` to the layout the default device gives an argument of its
+    shape.  Left free, the TPU compiler lays a carried cache out as its
+    one-entry update prefers, and relayouts the whole cache on entering and
+    leaving the layer scan; pinned, a donated cache is updated where it lies."""
+    dev = jax.devices()[0]
+    layout = dev.client.get_default_layout(x.dtype, x.shape, dev)
+    return with_layout_constraint(x, Layout.from_pjrt_layout(layout))
+
+
 def decode_step(params: Dict[str, Any], cfg: ArchConfig,
                 cache: Dict[str, Any], token: jax.Array, pos: jax.Array
                 ) -> Tuple[jax.Array, Dict[str, Any]]:
@@ -319,12 +330,17 @@ def decode_step(params: Dict[str, Any], cfg: ArchConfig,
     cos, sin = _rope_tables(cfg, max_pos)
 
     if cfg.block_pattern == "attn":
-        def body(h, inputs):
-            blk, ck, cv = inputs
-            h, ck, cv = _attn_block_decode(blk, h, ck, cv, pos, cos, sin, cfg)
-            return h, (ck, cv)
-        x, (ks, vs) = jax.lax.scan(body, x,
-                                   (params["blocks"], cache["k"], cache["v"]))
+        # the stacked cache is carried, and each layer writes its one new
+        # entry in place: with the cache donated, a step copies none of it
+        def body(carry, inputs):
+            h, ck, cv = carry
+            blk, layer = inputs
+            h, ck, cv = _attn_block_decode(blk, h, ck, cv, pos, cos, sin, cfg,
+                                           layer=layer)
+            return (h, _argument_layout(ck), _argument_layout(cv)), None
+        (x, ks, vs), _ = jax.lax.scan(
+            body, (x, cache["k"], cache["v"]),
+            (params["blocks"], jnp.arange(cfg.n_layers)))
         new_cache = {"k": ks, "v": vs}
     elif cfg.block_pattern == "mamba_shared_attn":
         x, new_cache = _hybrid_decode(params, cfg, cache, x, pos, cos, sin)

@@ -6,7 +6,9 @@ real batches (prefill = scanned decode, which works uniformly across the
 attention / hybrid / xlstm cache families) and integrates the Unimem
 runtime: params and the KV cache are registered as data objects by size
 only (jit owns the buffers), so the runtime profiles and plans them but
-moves none of their bytes.
+moves none of their bytes.  The step donates its cache argument, so each
+token updates the cache in place (``ServeStats.cache_donated`` counts the
+calls whose first step consumed the cache it was given).
 
 Each ``generate`` call is a ``unimem.generate`` span, holding the spans of
 its parts (``init_cache``, ``prefill``, ``decode``, ``output``): a step
@@ -44,6 +46,7 @@ def build_decode_step(cfg: ArchConfig, sample: str = "greedy") -> Callable:
 class ServeStats:
     prefill_tokens: int = 0
     decode_tokens: int = 0
+    cache_donated: int = 0      # generate calls whose first step took the cache
 
 
 class ServeEngine:
@@ -74,7 +77,7 @@ class ServeEngine:
         if runtime is not None:
             self._ns = (runtime.tenant(tenant, priority=priority, slo=slo)
                         if tenant else runtime)
-        self.step = jax.jit(build_decode_step(cfg))
+        self.step = jax.jit(build_decode_step(cfg), donate_argnums=(1,))
         self.stats = ServeStats()
 
     # ------------------------------------------------------------------
@@ -99,6 +102,7 @@ class ServeEngine:
             with span("init_cache"):
                 cache = lm.init_cache(self.cfg, B, self.max_seq)
             self._register(cache)
+            given = jax.tree_util.tree_leaves(cache)[0]
             with (self.runtime.iteration() if self.runtime is not None
                   else contextlib.nullcontext()):
                 tok = prompts[:, 0]
@@ -112,6 +116,8 @@ class ServeEngine:
                                                   prompts[:, i], jnp.int32(i))
                         self.stats.prefill_tokens += B
                     jax.block_until_ready((nxt, cache))
+                # a donated cache is deleted by the first step that takes it
+                self.stats.cache_donated += given.is_deleted()
                 tok = nxt
                 gen = []
                 with self._phase("decode"), span("decode"):

@@ -13,6 +13,7 @@ all import this file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -91,11 +92,14 @@ def test_ssd_scan_compiles_at_zamba2_width(one_chip):
     _assert_kernel(ssd_scan.lower(a, kq, v, kq).compile())
 
 
-def test_gemma_2b_decode_step_compiles_and_fits_one_chip(one_chip):
-    """The serving step at full width (B=8, max_seq=2048), as ServeEngine
-    jits it: it compiles for one v5e and its footprint fits in HBM."""
-    cfg = get_config("gemma-2b")
-    B, max_seq = 8, 2048
+def _serve_step(name, one_chip, monkeypatch, B=8, max_seq=2048):
+    """The serving step at full width, compiled for one v5e as ServeEngine
+    jits it (cache donated); returns (compiled, params, cache) specs.  The
+    step pins its carried cache to the default device's argument layout, so
+    that device is the described chip here, as it is on the chip."""
+    monkeypatch.setattr(jax, "devices",
+                        lambda *_, **__: list(one_chip.device_set))
+    cfg = get_config(name)
     place = lambda s: _spec(s.shape, s.dtype, one_chip)   # noqa: E731
     params = jax.tree_util.tree_map(place, jax.eval_shape(
         lambda: lm.init_params(cfg, jax.random.PRNGKey(0))))
@@ -103,13 +107,35 @@ def test_gemma_2b_decode_step_compiles_and_fits_one_chip(one_chip):
         lambda: lm.init_cache(cfg, B, max_seq)))
     token = _spec((B,), jnp.int32, one_chip)
     pos = _spec((), jnp.int32, one_chip)
-    compiled = jax.jit(build_decode_step(cfg)).lower(
+    compiled = jax.jit(build_decode_step(cfg), donate_argnums=(1,)).lower(
         params, cache, token, pos).compile()
+    return compiled, params, cache
+
+
+def _nbytes(tree):
+    return sum(l.size * l.dtype.itemsize for l in jax.tree_util.tree_leaves(tree))
+
+
+def test_gemma_2b_decode_step_compiles_and_fits_one_chip(one_chip,
+                                                         monkeypatch):
+    """The serving step at full width (B=8, max_seq=2048), as ServeEngine
+    jits it: it compiles for one v5e and its footprint fits in HBM."""
+    compiled, params, _ = _serve_step("gemma-2b", one_chip, monkeypatch)
     mem = compiled.memory_analysis()
-    param_bytes = sum(l.size * l.dtype.itemsize
-                      for l in jax.tree_util.tree_leaves(params))
+    param_bytes = _nbytes(params)
     assert param_bytes > 4.9e9                  # really full width
     assert mem.argument_size_in_bytes >= param_bytes
     footprint = (mem.argument_size_in_bytes + mem.output_size_in_bytes
                  + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert footprint < HBM_BYTES
+
+
+@pytest.mark.parametrize("name", ["gemma-2b", "chatglm3-6b"])
+def test_decode_step_updates_its_cache_in_place(one_chip, monkeypatch, name):
+    """The donated cache is the step's output buffer, and no operation
+    copies a whole stacked cache: each token writes one entry per layer."""
+    compiled, _, cache = _serve_step(name, one_chip, monkeypatch)
+    assert compiled.memory_analysis().alias_size_in_bytes >= _nbytes(cache)
+    shape = ",".join(map(str, cache["k"].shape))
+    whole_copy = re.compile(r"%copy[\w.-]* = \w+\[" + shape + r"\]")
+    assert not whole_copy.search(compiled.as_text())
