@@ -1,11 +1,9 @@
-"""Operation and byte counts the benchmark divides by measured time.
+"""Counts of a dense GQA decoder (``block_pattern`` ``attn``, gated MLP):
+the count module of every configuration that names none.
 
-Every count is the least work the algorithm needs, computed from the
-configuration's sizes alone (never from the program's own cost analysis):
-a share of a peak built on these counts cannot exceed 100% unless the
-program skipped work.  ``arch`` is any object with the configuration's
-fields (``n_layers``, ``d_model``, ``n_heads``, ...), as in
-``bench/configs/<name>.json``'s ``arch``.
+``arch`` is any object with the configuration's fields (``n_layers``,
+``d_model``, ``n_heads``, ...), as in ``bench/configs/<name>.json``'s
+``arch``.
 """
 
 from __future__ import annotations
@@ -59,3 +57,8 @@ def dense_decode_bytes(arch, batch: int, pos: int,
     kv_entry = 2 * arch.n_kv_heads * head_dim(arch) * kv_bytes   # k and v
     kv = arch.n_layers * batch * kv_entry * (pos + 1)
     return float(weights + kv)
+
+
+# the names ``bench/metrics/_decode.py`` reads from every count module
+decode_flops = dense_decode_flops
+decode_bytes = dense_decode_bytes

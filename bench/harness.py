@@ -60,6 +60,12 @@ class Cell:
         return load_module(self.bench_dir / "reference"
                            / f"{self.config['reference']}.py")
 
+    def counts(self):
+        """The configuration's count module: ``bench/counts/<counts>.py``,
+        the dense decoder's where the configuration names none."""
+        return load_module(self.bench_dir / "counts"
+                           / f"{self.config.get('counts', 'dense')}.py")
+
 
 def _reports(metric: dict, cell: str) -> bool:
     return "workloads" not in metric or cell in metric["workloads"]

@@ -1,6 +1,7 @@
 """The decode step's share of its roofline: the least time its work needs
 (the larger of its FLOPs over peak and its least HBM bytes over peak
-bandwidth: every weight once, the KV cache read up to the position, the new
+bandwidth, both from the configuration's count module; for a dense
+decoder every weight once, the KV cache read up to the position, the new
 KV entry written) over the device time of its programs in the trace."""
 
 from bench import peaks

@@ -38,6 +38,7 @@ class Run:
     def __init__(self, ctx, outcome, summary, device_kind):
         self.traffic = ctx.traffic
         self.arch = ctx.arch()
+        self.counts = ctx.cell.counts()
         self.counters = outcome.counters
         self.extra = outcome.extra
         self.trace = summary

@@ -8,7 +8,19 @@ from the seed without anything the program made.  Leaves under a
 ``*blocks`` key are stacked per layer and drawn one layer at a time, which
 bounds the temporary float32 draw to one layer.  Biases and norm scales
 get random values (the program starts them at constants), so the
-comparison covers them too.
+comparison covers them too.  Every leaf the program's initializer makes is
+drawn, of any rank: a matrix by its fan-in, and a vector no other rule
+names (a bias: ``conv_b``, a LayerNorm's ``*_bias``) as ``z * 0.1``.
+
+Mamba2's per-head leaves (``a_log``, ``dt_bias``, ``d_skip``) follow the
+published Mamba2 initialisation (Dao & Gu, arXiv:2405.21060), with the
+time-step range of Zamba2's configuration: A uniform in [1, 16], dt
+log-uniform in [1e-3, 1e-1] and floored at 1e-4, D about 1.  The generic
+draw would give every head a per-token decay near exp(-softplus(N(0, 1))),
+about 0.3-0.7: the state would forget within a few tokens, so no served
+token would depend on state the cache carried further than that.  With
+these, the heads' time constants 1 / (dt A) at their biases spread from
+under one token to a thousand.
 """
 
 from __future__ import annotations
@@ -17,6 +29,10 @@ import math
 
 import jax
 import jax.numpy as jnp
+
+A_RANGE = (1.0, 16.0)          # Mamba2: A = -exp(a_log), A uniform here
+DT_RANGE = (1e-3, 1e-1)        # Zamba2: time_step_min, time_step_max
+DT_FLOOR = 1e-4                # Zamba2: time_step_floor
 
 
 def shapes(arch):
@@ -41,6 +57,17 @@ def draw_leaf(name: str, key, shape, dtype):
     elif name.endswith("_scale") or name == "norm":
         v = z * 0.1
     elif name in ("bq", "bk", "bv"):
+        v = z * 0.1
+    elif name == "a_log":
+        v = jnp.log(jax.random.uniform(key, shape, jnp.float32, *A_RANGE))
+    elif name == "dt_bias":                 # softplus^-1 of dt
+        lo, hi = (math.log(t) for t in DT_RANGE)
+        dt = jnp.maximum(jnp.exp(jax.random.uniform(
+            key, shape, jnp.float32, lo, hi)), DT_FLOOR)
+        v = dt + jnp.log(-jnp.expm1(-dt))
+    elif name == "d_skip":
+        v = 1.0 + z * 0.1
+    elif len(shape) == 1:                   # other biases
         v = z * 0.1
     else:                                   # projections: 1/sqrt(fan_in)
         v = z / math.sqrt(shape[-2])

@@ -1,5 +1,6 @@
 """FLOP and byte counts against hand counts at tiny shapes, parameter
-counts against the program's own tree, and the peaks table."""
+counts against the program's own tree, the count module a configuration
+without a ``counts`` key gets, and the peaks table."""
 
 import sys
 from pathlib import Path
@@ -9,7 +10,7 @@ import pytest
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[2]))
 
-from bench import counts, peaks  # noqa: E402
+from bench import counts, harness, peaks  # noqa: E402
 
 DENSE = SimpleNamespace(n_layers=2, d_model=8, n_heads=4, n_kv_heads=2,
                         head_dim=None, d_ff=16, vocab_size=32,
@@ -44,6 +45,22 @@ def test_parameter_counts_match_the_program_tree(pattern):
     tree = jax.eval_shape(lambda k: lm.init_params(arch, k),
                           jax.random.PRNGKey(0))
     assert sum(l.size for l in jax.tree_util.tree_leaves(tree)) == want
+
+
+def test_a_configuration_naming_no_counts_gets_the_dense_counts():
+    from repro.configs.base import ArchConfig
+    cell = harness.find_cell("chatglm3-6b.serve")
+    assert "counts" not in cell.config
+    module = cell.counts()
+    arch = ArchConfig(**cell.config["arch"])
+    # the values the decode metrics divided by before count modules could
+    # be named (batch 8, at the first, a typical and the last position)
+    for pos, flops, nbytes in [(0, 95633801216.0, 11954786304.0),
+                               (120, 96074203136.0, 11982311424.0),
+                               (2047, 103146323968.0, 12424318976.0)]:
+        assert module.decode_flops(arch, 8, pos) == flops
+        assert module.decode_bytes(arch, 8, pos) == nbytes
+        assert counts.dense_decode_flops(arch, 8, pos) == flops
 
 
 def test_peaks_table_and_share_guard():
