@@ -1,4 +1,5 @@
-"""Architecture config registry: the 10 assigned architectures."""
+"""Architecture config registry: the 10 assigned architectures and
+``zamba2-7b``, the published Zamba2 structure."""
 
 from typing import Dict, List
 
@@ -13,15 +14,17 @@ from .moonshot_v1_16b_a3b import CONFIG as MOONSHOT_V1_16B_A3B
 from .dbrx_132b import CONFIG as DBRX_132B
 from .musicgen_large import CONFIG as MUSICGEN_LARGE
 from .xlstm_350m import CONFIG as XLSTM_350M
+from .zamba2_7b import CONFIG as ZAMBA2_7B
 
 ARCHS: Dict[str, ArchConfig] = {c.name: c for c in [
     ZAMBA2_1P2B, PHI3_VISION_4P2B, NEMOTRON4_340B, YI_6B, GEMMA_2B,
-    CHATGLM3_6B, MOONSHOT_V1_16B_A3B, DBRX_132B, MUSICGEN_LARGE, XLSTM_350M,
+    CHATGLM3_6B, MOONSHOT_V1_16B_A3B, DBRX_132B, MUSICGEN_LARGE, XLSTM_350M, ZAMBA2_7B,
 ]}
 
 # short aliases for --arch flags
 ALIASES = {
     "zamba2-1.2b": "zamba2-1.2b", "zamba2": "zamba2-1.2b",
+    "zamba2-7b": "zamba2-7b",
     "phi-3-vision-4.2b": "phi-3-vision-4.2b", "phi3v": "phi-3-vision-4.2b",
     "nemotron-4-340b": "nemotron-4-340b", "nemotron": "nemotron-4-340b",
     "yi-6b": "yi-6b", "yi": "yi-6b",

@@ -57,7 +57,13 @@ class ArchConfig:
     ssm_head_dim: int = 64
     ssm_expand: int = 2
     ssm_conv: int = 4
+    ssm_groups: int = 1                     # mamba2 ngroups: heads share B/C
     attn_every: int = 0                     # hybrid: shared attn block period
+    hybrid_layer_ids: Tuple[int, ...] = ()  # hybrid: layers a shared block
+                                            # feeds (default: every attn_every)
+    mem_blocks: int = 1                     # hybrid: shared blocks, in turn
+    mlp_adapter_rank: int = 0               # per-application LoRA on gate/up
+    attn_scale_frac: float = 1.0            # scores * (frac * head_dim)^-0.5
     slstm_every: int = 0                    # xlstm: sLSTM block period
     block_pattern: str = "attn"             # attn|mamba_shared_attn|xlstm
     # modality frontend stub
@@ -67,10 +73,29 @@ class ArchConfig:
     supports_long_context: bool = False     # sub-quadratic -> run long_500k
     max_position: int = 544 * 1024
 
+    def __post_init__(self):
+        # a configuration file gives the ids as a list; keep the config hashable
+        object.__setattr__(self, "hybrid_layer_ids",
+                           tuple(self.hybrid_layer_ids))
+
     # ------------------------------------------------------------- derived
     @property
     def resolved_head_dim(self) -> int:
         return self.head_dim or (self.d_model // self.n_heads)
+
+    @property
+    def hybrid_ids(self) -> Tuple[int, ...]:
+        """Layers whose Mamba2 input a shared-block application feeds."""
+        ids = self.hybrid_layer_ids or range(0, self.n_layers, self.attn_every)
+        return tuple(i for i in ids if i < self.n_layers)
+
+    @property
+    def attn_in_dim(self) -> int:
+        """Width the attention projections read: a hybrid's shared block
+        reads concat(hidden, embedding)."""
+        if self.block_pattern == "mamba_shared_attn":
+            return 2 * self.d_model
+        return self.d_model
 
     @property
     def is_moe(self) -> bool:
@@ -86,15 +111,17 @@ class ArchConfig:
         if self.block_pattern == "mamba_shared_attn":
             d_in = self.ssm_expand * d
             n_h = d_in // self.ssm_head_dim
-            per_layer = (d * (2 * d_in + 2 * self.ssm_state) + 3 * n_h
-                         + d_in * self.ssm_conv + d_in * d + 2 * d)
-            p += per_layer * self.n_layers
-            # one shared attention block (+ its mlp) reused across the stack
-            q = d * self.n_heads * hd
-            kv = 2 * d * self.n_kv_heads * hd
-            o = self.n_heads * hd * d
-            p += q + kv + o + 3 * d * self.d_ff + 2 * d
-            return p
+            conv_ch = d_in + 2 * self.ssm_groups * self.ssm_state
+            mamba = (d * (d_in + conv_ch + n_h)          # in_proj: z, xBC, dt
+                     + (self.ssm_conv + 1) * conv_ch     # conv weight, bias
+                     + 3 * n_h + d_in + d_in * d + d)    # A, dt bias, D, norm,
+            p += mamba * self.n_layers + d               # out_proj, ln; final
+            a, f = self.attn_in_dim, self.d_ff
+            attn = (a * self.n_heads * hd + 2 * a * self.n_kv_heads * hd
+                    + self.n_heads * hd * d)
+            p += self.mem_blocks * (a + attn + d + 3 * d * f)
+            per_app = d * d + self.mlp_adapter_rank * (d + 2 * f)
+            return p + per_app * len(self.hybrid_ids)
         if self.block_pattern == "xlstm":
             d_in = self.ssm_expand * d
             H = self.n_heads
@@ -137,12 +164,17 @@ class ArchConfig:
         return True, ""
 
     def reduced(self) -> "ArchConfig":
-        """Small same-family config for CPU smoke tests."""
+        """Small same-family config for CPU smoke tests.  A hybrid with
+        explicit ``hybrid_layer_ids`` keeps uneven ids, two memory blocks
+        applied in turn, grouped B/C and its adapters."""
+        n_layers = 2
+        if self.block_pattern == "mamba_shared_attn":
+            n_layers = (5 if self.hybrid_layer_ids
+                        else max(2, min(4, (self.attn_every or 2) + 1)))
         return dataclasses.replace(
             self,
             name=self.name + "-smoke",
-            n_layers=max(2, min(4, (self.attn_every or 2) + 1)
-                         if self.block_pattern == "mamba_shared_attn" else 2),
+            n_layers=n_layers,
             d_model=64,
             n_heads=4,
             n_kv_heads=min(4, max(1, self.n_kv_heads * 4 // self.n_heads)),
@@ -155,7 +187,11 @@ class ArchConfig:
             moe_shared_experts=min(1, self.moe_shared_experts),
             ssm_state=16 if self.ssm_state else 0,
             ssm_head_dim=16 if self.ssm_state else 64,
+            ssm_groups=min(2, self.ssm_groups),
             attn_every=2 if self.attn_every else 0,
+            hybrid_layer_ids=(1, 3, 4) if self.hybrid_layer_ids else (),
+            mem_blocks=min(2, self.mem_blocks),
+            mlp_adapter_rank=8 if self.mlp_adapter_rank else 0,
             slstm_every=2 if self.slstm_every else 0,
             frontend_tokens=4 if self.frontend else 0,
             max_position=1024,

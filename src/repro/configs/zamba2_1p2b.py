@@ -2,6 +2,14 @@
 
 38L d_model=2048 32H (GQA kv=32) d_ff=8192 vocab=32000, ssm_state=64
 [arXiv:2411.15242; hf]
+
+Unconfirmed: this model's ``config.json`` is not in the repository, so
+only the sizes above come from the paper's table; the shared block's head
+size (``d_model // n_heads`` over the 2 * d_model-wide concatenation), the
+number of memory blocks (1), the MLP adapters (none), ungrouped B/C, the
+MLP's activation and gating, and the hybrid layers (every 6th from layer
+0, from ``attn_every``) are this preset's own choices.  The published
+Zamba2 structure is ``zamba2-7b``.
 """
 
 from .base import ArchConfig

@@ -9,10 +9,16 @@ and costs the Python enter and exit alone, so there is no switch.
 
 Names are static; what varies (an object, a byte count, a phase) goes in
 as keyword arguments, which the trace keeps as the event's stats.
+
+``scope(name)`` is the device-side counterpart: a ``jax.named_scope``
+named ``unimem.<name>`` around model code being traced for jit.  It costs
+nothing at run time; the compiled operations carry the name in their
+``op_name`` metadata, which the device trace shows for each operation.
 """
 
 from __future__ import annotations
 
+import jax
 from jax.profiler import TraceAnnotation
 
 PREFIX = "unimem."
@@ -20,3 +26,7 @@ PREFIX = "unimem."
 
 def span(name: str, **args) -> TraceAnnotation:
     return TraceAnnotation(PREFIX + name, **args)
+
+
+def scope(name: str):
+    return jax.named_scope(PREFIX + name)

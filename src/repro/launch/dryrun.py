@@ -140,8 +140,8 @@ def emulation_shadow_bytes(hlo_text: str) -> int:
 
 def _reduced_layer_counts(cfg: ArchConfig) -> Tuple[int, int]:
     if cfg.block_pattern == "mamba_shared_attn":
-        g = cfg.attn_every
-        return g, 2 * g
+        ids = cfg.hybrid_ids        # each probe ends before an application
+        return ids[1], ids[2]
     if cfg.block_pattern == "xlstm":
         g = cfg.slstm_every or 2
         return g, 2 * g

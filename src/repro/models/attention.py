@@ -25,11 +25,12 @@ NEG_INF = -2.0 ** 30
 def init_attn_params(key: jax.Array, cfg: ArchConfig,
                      dtype=jnp.bfloat16) -> Dict[str, jax.Array]:
     d, H, K, Dh = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
+    a = cfg.attn_in_dim            # what q/k/v read; wo writes d_model
     ks = split_keys(key, 4)
     p = {
-        "wq": dense_init(ks[0], (d, H * Dh), dtype),
-        "wk": dense_init(ks[1], (d, K * Dh), dtype),
-        "wv": dense_init(ks[2], (d, K * Dh), dtype),
+        "wq": dense_init(ks[0], (a, H * Dh), dtype),
+        "wk": dense_init(ks[1], (a, K * Dh), dtype),
+        "wv": dense_init(ks[2], (a, K * Dh), dtype),
         "wo": dense_init(ks[3], (H * Dh, d), dtype),
     }
     if cfg.attn_bias:
@@ -39,22 +40,30 @@ def init_attn_params(key: jax.Array, cfg: ArchConfig,
     return p
 
 
+def score_scale(cfg: ArchConfig) -> float:
+    """What q.k is multiplied by: (attn_scale_frac * head_dim)^-0.5."""
+    return 1.0 / math.sqrt(cfg.attn_scale_frac * cfg.resolved_head_dim)
+
+
 # ------------------------------------------------- chunked causal attention
 def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                       *, causal: bool = True, chunk: int = 1024,
-                      q_chunk: int = 512, q_offset: int = 0) -> jax.Array:
+                      q_chunk: int = 512, q_offset: int = 0,
+                      scale: Optional[float] = None) -> jax.Array:
     """Flash-style attention blocked in BOTH directions (never materializes
     more than a (bq, bk) score tile per head group).
 
     q: (B, S, H, D); k/v: (B, T, K, D) with H = G*K.  ``q_offset``: absolute
     position of q[0] (decode / chunked prefill).  Outer scan over q tiles,
     inner scan over KV tiles with the running (max, sum, acc) triple — the
-    same loop structure as the Pallas kernel grid.
+    same loop structure as the Pallas kernel grid.  ``scale`` multiplies
+    the scores (default D^-0.5).
     """
     B, S, H, D = q.shape
     T, K = k.shape[1], k.shape[2]
     G = H // K
-    scale = 1.0 / math.sqrt(D)
+    if scale is None:
+        scale = 1.0 / math.sqrt(D)
 
     bq = min(q_chunk, S)
     nq = -(-S // bq)
@@ -130,7 +139,7 @@ def attn_forward(params: Dict[str, jax.Array], x: jax.Array,
         q = apply_rope(q, cos, sin, positions=pos, rotary_dim=rd)
         k = apply_rope(k, cos, sin, positions=pos, rotary_dim=rd)
     out = chunked_attention(q, k, v, causal=True, chunk=kv_chunk,
-                            q_offset=q_offset)
+                            q_offset=q_offset, scale=score_scale(cfg))
     return out.reshape(B, S, H * Dh) @ params["wo"]
 
 
@@ -142,7 +151,8 @@ def attn_decode(params: Dict[str, jax.Array], x: jax.Array,
                 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """One-token decode against a KV cache.
 
-    x: (B, 1, d); cache_k/v: (B, S_max, K, Dh); pos: scalar current length.
+    x: (B, 1, attn_in_dim); cache_k/v: (B, S_max, K, Dh); pos: scalar
+    current length.
     With ``layer``, cache_k/v are the whole stacked cache (L, B, S_max, K,
     Dh): the new entry is written at (layer, :, pos) and the chunks are read
     from that layer of it, so a layer scan that carries the cache updates
@@ -181,8 +191,7 @@ def attn_decode(params: Dict[str, jax.Array], x: jax.Array,
     # flash-decoding: stream KV chunks with a running softmax so the score
     # tensor never exceeds (B, K, G, chunk) — bounded at 500k-token caches
     G = H // K
-    scale = 1.0 / math.sqrt(Dh)
-    qf = (q.astype(jnp.float32) * scale).reshape(B, K, G, Dh)
+    qf = (q.astype(jnp.float32) * score_scale(cfg)).reshape(B, K, G, Dh)
     bk = min(kv_chunk, S_max)
     while S_max % bk:          # keep chunks aligned without padding copies
         bk //= 2

@@ -69,9 +69,15 @@ def squared_relu(x: jax.Array) -> jax.Array:
     return r * r
 
 
+def gelu_exact(x: jax.Array) -> jax.Array:
+    """GELU by the error function (``"gelu"`` is the tanh approximation)."""
+    return jax.nn.gelu(x, approximate=False)
+
+
 ACTIVATIONS: dict = {
     "silu": jax.nn.silu,
     "gelu": jax.nn.gelu,
+    "gelu_exact": gelu_exact,
     "relu": jax.nn.relu,
     "squared_relu": squared_relu,
 }

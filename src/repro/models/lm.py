@@ -4,9 +4,11 @@ Three block patterns share one LM skeleton (embed -> blocks -> norm -> head):
 
 * ``attn``              — dense / MoE / VLM / audio transformers; layers are
                           stacked and scanned (``lax.scan`` keeps HLO small).
-* ``mamba_shared_attn`` — zamba2: Mamba2 backbone, one *shared* attention
-                          block (own KV per application) every ``attn_every``
-                          layers.
+* ``mamba_shared_attn`` — zamba2: Mamba2 backbone; at each of
+                          ``hybrid_ids`` one of ``mem_blocks`` *shared*
+                          attention+MLP blocks, in turn (own KV, ``linear``
+                          and adapter per application), feeds that layer's
+                          Mamba2 input.
 * ``xlstm``             — mLSTM stacks with an sLSTM block every
                           ``slstm_every`` layers.
 
@@ -32,6 +34,7 @@ import jax.numpy as jnp
 from jax.experimental.layout import Layout, with_layout_constraint
 
 from ..configs.base import ArchConfig
+from ..core.spans import scope
 from . import attention, mamba2, mlp as mlp_mod, moe as moe_mod, xlstm
 from .common import (dense_init, embed_init, embed_lookup, layer_norm,
                      rms_norm, rope_frequencies, shard_hint, split_keys)
@@ -46,11 +49,13 @@ def _norm(params_block: Dict[str, jax.Array], name: str, x: jax.Array,
     return rms_norm(x, params_block[f"{name}_scale"])
 
 
-def _init_norm(cfg: ArchConfig, name: str, dtype=jnp.bfloat16):
-    p = {f"{name}_scale": jnp.zeros((cfg.d_model,), dtype)}
+def _init_norm(cfg: ArchConfig, name: str, dtype=jnp.bfloat16,
+               width: Optional[int] = None):
+    width = width or cfg.d_model
+    p = {f"{name}_scale": jnp.zeros((width,), dtype)}
     if cfg.norm == "layer":
-        p[f"{name}_scale"] = jnp.ones((cfg.d_model,), dtype)
-        p[f"{name}_bias"] = jnp.zeros((cfg.d_model,), dtype)
+        p[f"{name}_scale"] = jnp.ones((width,), dtype)
+        p[f"{name}_bias"] = jnp.zeros((width,), dtype)
     return p
 
 
@@ -95,6 +100,64 @@ def _attn_block_decode(block, x, ck, cv, pos, cos, sin, cfg, layer=None):
     return x, ck, cv
 
 
+# ------------------------------------------------------------ hybrid blocks
+def _init_shared_block(key: jax.Array, cfg: ArchConfig, dtype=jnp.bfloat16):
+    ks = split_keys(key, 2)
+    p = {"attn": attention.init_attn_params(ks[0], cfg, dtype),
+         "mlp": mlp_mod.init_mlp_params(ks[1], cfg, dtype=dtype)}
+    p.update(_init_norm(cfg, "ln1", dtype, width=cfg.attn_in_dim))
+    p.update(_init_norm(cfg, "ln2", dtype))
+    return p
+
+
+def _init_application(key: jax.Array, cfg: ArchConfig, dtype=jnp.bfloat16):
+    """What one application of a shared block owns: the ``linear`` into the
+    residual stream and the MLP adapter."""
+    ks = split_keys(key, 2)
+    p = {"linear": dense_init(ks[0], (cfg.d_model, cfg.d_model), dtype)}
+    if cfg.mlp_adapter_rank:
+        p.update(mlp_mod.init_adapter_params(ks[1], cfg, dtype))
+    return p
+
+
+def _shared_apply(params, cfg, j, x, e, attend):
+    """Application ``j``: linear_j(shared_{j mod mem_blocks}([x; e])), with
+    no residual inside the block.  ``attend(attn_params, h)`` is the
+    attention (over the sequence or against the cache): (out, extra)."""
+    blk, app = params["shared"][j % cfg.mem_blocks], params["apps"][j]
+    h = jnp.concatenate([x, e], axis=-1)
+    out, extra = attend(blk["attn"], _norm(blk, "ln1", h, cfg))
+    h = mlp_mod.mlp_forward(blk["mlp"], _norm(blk, "ln2", out, cfg), cfg,
+                            adapter=app if cfg.mlp_adapter_rank else None)
+    return h @ app["linear"], extra
+
+
+def _hybrid_segments(cfg: ArchConfig):
+    """(application or None, first layer, end): the Mamba2 layers run in
+    order, application j feeding the input of layer ``hybrid_ids[j]``."""
+    ids = cfg.hybrid_ids
+    if not ids:
+        return [(None, 0, cfg.n_layers)]
+    ends = list(ids[1:]) + [cfg.n_layers]
+    lead = [(None, 0, ids[0])] if ids[0] else []
+    return lead + [(j, i, end) for j, (i, end) in enumerate(zip(ids, ends))]
+
+
+def _layer(tree, i):
+    """Layer ``i`` of a stacked tree, read where it lies."""
+    return jax.tree_util.tree_map(
+        lambda w: jax.lax.dynamic_index_in_dim(w, i, keepdims=False), tree)
+
+
+def _mamba_layers(layer_fn, carry, t, start, end):
+    """Layers start..end-1 in a loop: ``layer_fn(carry, t, i)``; ``t`` (the
+    application's output) feeds layer ``start``'s input, zeros the rest."""
+    def body(i, c):
+        carry, t = c
+        return layer_fn(carry, t, i), jnp.zeros_like(t)
+    return jax.lax.fori_loop(start, end, body, (carry, t))[0]
+
+
 # ----------------------------------------------------------------- init all
 def init_params(cfg: ArchConfig, key: jax.Array,
                 dtype=jnp.bfloat16) -> Dict[str, Any]:
@@ -119,7 +182,10 @@ def init_params(cfg: ArchConfig, key: jax.Array,
             p.update(_init_norm(cfg, "ln1", dtype))
             return p
         params["mamba_blocks"] = jax.vmap(mamba_block)(layer_keys)
-        params["shared_attn"] = _init_attn_block(ks[3], cfg, dtype)
+        params["shared"] = [_init_shared_block(k, cfg, dtype)
+                            for k in split_keys(ks[3], cfg.mem_blocks)]
+        params["apps"] = [_init_application(k, cfg, dtype) for k in
+                          split_keys(ks[5], len(cfg.hybrid_ids))]
     elif cfg.block_pattern == "xlstm":
         n_s = cfg.n_layers // cfg.slstm_every if cfg.slstm_every else 0
         n_m = cfg.n_layers - n_s
@@ -191,29 +257,27 @@ def forward(params: Dict[str, Any], cfg: ArchConfig, tokens: jax.Array,
 
 
 def _hybrid_forward(params, cfg, x, cos, sin, remat=False):
-    """zamba2: shared attn block every ``attn_every`` Mamba2 layers."""
-    L, k = cfg.n_layers, cfg.attn_every
-    blocks = params["mamba_blocks"]
+    """zamba2: x <- x + mamba_i(norm(x + t_i)), t_i the output of the shared
+    block applied at layer i (zero at the other layers)."""
+    e = x                                       # the embedding output
 
-    def shared(h):
-        return _attn_block_fwd(params["shared_attn"], h, cos, sin, cfg)[0]
+    def shared(j, h):
+        return _shared_apply(params, cfg, j, h, e, lambda a, hn: (
+            attention.attn_forward(a, hn, cos, sin, cfg), None))[0]
 
-    def body(h, blk):
-        hn = _norm(blk, "ln1", h, cfg)
-        h = h + mamba2.mamba2_forward(blk, hn, cfg)
-        return shard_hint(h, "dp", None, "model"), None
+    def layer(h, t, i):
+        blk = _layer(params["mamba_blocks"], i)
+        hn = _norm(blk, "ln1", h + t, cfg)
+        return shard_hint(h + mamba2.mamba2_forward(blk, hn, cfg),
+                          "dp", None, "model")
 
     if remat:
-        shared = jax.checkpoint(shared)
-        body = jax.checkpoint(body,
-                              policy=jax.checkpoint_policies.nothing_saveable)
-    done = 0
-    while done < L:
-        x = shared(x)
-        take = min(k, L - done)
-        chunk = jax.tree_util.tree_map(lambda w: w[done:done + take], blocks)
-        x, _ = jax.lax.scan(body, x, chunk)
-        done += take
+        shared = jax.checkpoint(shared, static_argnums=0)
+        layer = jax.checkpoint(layer,
+                               policy=jax.checkpoint_policies.nothing_saveable)
+    for j, start, end in _hybrid_segments(cfg):
+        t = jnp.zeros_like(x) if j is None else shared(j, x)
+        x = _mamba_layers(layer, x, t, start, end)
     return x
 
 
@@ -285,15 +349,10 @@ def init_cache(cfg: ArchConfig, batch: int, max_seq: int,
         return {"k": jnp.zeros(shape, kv_dtype),
                 "v": jnp.zeros(shape, kv_dtype)}
     if cfg.block_pattern == "mamba_shared_attn":
-        n_apps = -(-cfg.n_layers // cfg.attn_every)
-        m = mamba2.init_mamba2_cache(cfg, batch)
-        return {
-            "mamba": jax.tree_util.tree_map(
-                lambda z: jnp.broadcast_to(
-                    z[None], (cfg.n_layers,) + z.shape), m),
-            "k": jnp.zeros((n_apps, batch, max_seq, K, Dh), kv_dtype),
-            "v": jnp.zeros((n_apps, batch, max_seq, K, Dh), kv_dtype),
-        }
+        shape = (len(cfg.hybrid_ids), batch, max_seq, K, Dh)  # per application
+        return {**mamba2.init_mamba2_cache(cfg, batch, cfg.n_layers),
+                "k": jnp.zeros(shape, kv_dtype),
+                "v": jnp.zeros(shape, kv_dtype)}
     # xlstm
     period = cfg.slstm_every or (cfg.n_layers + 1)
     n_s = cfg.n_layers // period
@@ -355,33 +414,43 @@ def decode_step(params: Dict[str, Any], cfg: ArchConfig,
 
 
 def _hybrid_decode(params, cfg, cache, x, pos, cos, sin):
-    L, k = cfg.n_layers, cfg.attn_every
-    blocks = params["mamba_blocks"]
-    new_m = []
-    ks, vs = [], []
-    done, app = 0, 0
-    while done < L:
-        x, ck, cv = _attn_block_decode(
-            params["shared_attn"], x, cache["k"][app], cache["v"][app],
-            pos, cos, sin, cfg)
-        ks.append(ck)
-        vs.append(cv)
-        app += 1
-        take = min(k, L - done)
-        chunk = jax.tree_util.tree_map(lambda w: w[done:done + take], blocks)
-        mcache = jax.tree_util.tree_map(lambda w: w[done:done + take],
-                                        cache["mamba"])
-        def body(h, inputs):
-            blk, mc = inputs
-            hn = _norm(blk, "ln1", h, cfg)
-            out, mc2 = mamba2.mamba2_decode(blk, hn, mc, cfg)
-            return h + out, mc2
-        x, mc_new = jax.lax.scan(body, x, (chunk, mcache))
-        new_m.append(mc_new)
-        done += take
-    mamba_cache = jax.tree_util.tree_map(
-        lambda *xs: jnp.concatenate(xs, axis=0), *new_m)
-    return x, {"mamba": mamba_cache, "k": jnp.stack(ks), "v": jnp.stack(vs)}
+    """The hybrid step carries the stacked KV, SSM and conv caches: each
+    application writes its one KV entry, each Mamba2 layer its own state
+    slice, and layer weights are read in the loop, so with the cache
+    donated a step copies none of them."""
+    e = x
+    ks, vs = cache["k"], cache["v"]
+
+    def layer(c, t, i):
+        h, ssm, conv = c
+        with scope("mamba2"):
+            blk = _layer(params["mamba_blocks"], i)
+            hn = _norm(blk, "ln1", h + t, cfg)
+            out, mc = mamba2.mamba2_decode(
+                blk, hn, _layer({"ssm": ssm, "conv": conv}, i), cfg)
+            ssm, conv = (_argument_layout(jax.lax.dynamic_update_index_in_dim(
+                whole, mc[name], i, 0)) for name, whole in
+                (("ssm", ssm), ("conv", conv)))
+            return h + out, ssm, conv
+
+    carry = (x, cache["ssm"], cache["conv"])
+    for j, start, end in _hybrid_segments(cfg):
+        t = jnp.zeros_like(x)
+        if j is not None:
+            with scope("shared_block"):
+                t, (ks, vs) = _shared_apply(
+                    params, cfg, j, carry[0], e,
+                    lambda a, hn: _attend_cached(a, hn, ks, vs, pos, cos, sin,
+                                                 cfg, j))
+        carry = _mamba_layers(layer, carry, t, start, end)
+    x, ssm, conv = carry
+    return x, {"ssm": ssm, "conv": conv, "k": ks, "v": vs}
+
+
+def _attend_cached(a, h, ks, vs, pos, cos, sin, cfg, j):
+    out, ks, vs = attention.attn_decode(a, h, ks, vs, pos, cos, sin, cfg,
+                                        layer=j)
+    return out, (_argument_layout(ks), _argument_layout(vs))
 
 
 def _xlstm_decode(params, cfg, cache, x):

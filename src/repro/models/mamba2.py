@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ArchConfig
-from .common import dense_init, rms_norm, split_keys
+from .common import dense_init, split_keys
 
 
 # ---------------------------------------------------------------------------
@@ -60,7 +60,9 @@ def chunked_linear_scan(a: jax.Array, k: jax.Array, v: jax.Array,
         seg = cum[:, :, None, :] - cum[:, None, :, :]     # (B, Q, Q, H)
         iq = jnp.arange(Q)
         causal = (iq[:, None] >= iq[None, :])
-        mask = jnp.where(causal[None, :, :, None], jnp.exp(seg), 0.0)
+        # masked before the exponential: above the diagonal seg grows
+        # without bound, and exp's gradient there would be inf times 0
+        mask = jnp.exp(jnp.where(causal[None, :, :, None], seg, -jnp.inf))
         scores = jnp.einsum("bihn,bjhn->bijh", q_b.astype(jnp.float32),
                             k_b.astype(jnp.float32)) * mask
         y_intra = jnp.einsum("bijh,bjhp->bihp", scores,
@@ -98,17 +100,22 @@ def linear_scan_step(state: jax.Array, a: jax.Array, k: jax.Array,
 
 
 # ---------------------------------------------------------------------------
+def _dims(cfg: ArchConfig):
+    """(d_in, H, P, G, N, conv channels): heads h of group h // (H // G)
+    read that group's B and C."""
+    d_in = cfg.ssm_expand * cfg.d_model
+    P, G, N = cfg.ssm_head_dim, cfg.ssm_groups, cfg.ssm_state
+    return d_in, d_in // P, P, G, N, d_in + 2 * G * N
+
+
 def init_mamba2_params(key: jax.Array, cfg: ArchConfig,
                        dtype=jnp.bfloat16) -> Dict[str, jax.Array]:
     d = cfg.d_model
-    d_in = cfg.ssm_expand * d
-    N = cfg.ssm_state
-    H = d_in // cfg.ssm_head_dim
-    conv_ch = d_in + 2 * N
+    d_in, H, _, _, _, conv_ch = _dims(cfg)
     ks = split_keys(key, 4)
     return {
-        # order: [z (d_in), x (d_in), B (N), C (N), dt (H)]
-        "in_proj": dense_init(ks[0], (d, 2 * d_in + 2 * N + H), dtype),
+        # order: [z (d_in), x (d_in), B (G*N), C (G*N), dt (H)]
+        "in_proj": dense_init(ks[0], (d, d_in + conv_ch + H), dtype),
         "conv_w": dense_init(ks[1], (cfg.ssm_conv, conv_ch), dtype,
                              scale=0.5),
         "conv_b": jnp.zeros((conv_ch,), dtype),
@@ -121,13 +128,36 @@ def init_mamba2_params(key: jax.Array, cfg: ArchConfig,
 
 
 def _split_proj(proj: jax.Array, cfg: ArchConfig):
-    d_in = cfg.ssm_expand * cfg.d_model
-    N = cfg.ssm_state
-    H = d_in // cfg.ssm_head_dim
-    z = proj[..., :d_in]
-    xbc = proj[..., d_in:d_in + d_in + 2 * N]
-    dt = proj[..., d_in + d_in + 2 * N:]
-    return z, xbc, dt, d_in, N, H
+    d_in, _, _, _, _, conv_ch = _dims(cfg)
+    return (proj[..., :d_in], proj[..., d_in:d_in + conv_ch],
+            proj[..., d_in + conv_ch:])
+
+
+def _heads(xbc: jax.Array, cfg: ArchConfig):
+    """Split the convolved channels into x (..., H, P) and the B and C each
+    head reads, (..., H, N)."""
+    d_in, H, P, G, N, _ = _dims(cfg)
+    lead = xbc.shape[:-1]
+
+    def per_head(m):
+        m = m.reshape(lead + (G, 1, N))
+        return jnp.broadcast_to(m, lead + (G, H // G, N)).reshape(
+            lead + (H, N))
+
+    return (xbc[..., :d_in].reshape(lead + (H, P)),
+            per_head(xbc[..., d_in:d_in + G * N]),
+            per_head(xbc[..., d_in + G * N:]))
+
+
+def _gated_norm(y: jax.Array, z: jax.Array, scale: jax.Array,
+                cfg: ArchConfig) -> jax.Array:
+    """RMSNorm of y * silu(z) taken per group of d_in / G channels, in
+    float32.  y: (..., d_in) float32."""
+    _, _, _, G, _, _ = _dims(cfg)
+    g = y * jax.nn.silu(z.astype(jnp.float32))
+    g = g.reshape(g.shape[:-1] + (G, -1))
+    g = g * jax.lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True) + 1e-6)
+    return g.reshape(y.shape) * (1.0 + scale.astype(jnp.float32))
 
 
 def _causal_conv(xbc: jax.Array, w: jax.Array, b: jax.Array) -> jax.Array:
@@ -142,64 +172,50 @@ def mamba2_forward(params: Dict[str, jax.Array], x: jax.Array,
                    cfg: ArchConfig, *, chunk: int = 256) -> jax.Array:
     """Full-sequence Mamba-2 block.  x: (B, S, d) -> (B, S, d)."""
     B, S, d = x.shape
-    P = cfg.ssm_head_dim
-    proj = x @ params["in_proj"]
-    z, xbc, dt, d_in, N, H = _split_proj(proj, cfg)
+    d_in = _dims(cfg)[0]
+    z, xbc, dt = _split_proj(x @ params["in_proj"], cfg)
     xbc = _causal_conv(xbc, params["conv_w"], params["conv_b"])
-    xs = xbc[..., :d_in].reshape(B, S, H, P)
-    Bmat = xbc[..., d_in:d_in + N]                       # (B, S, N)
-    Cmat = xbc[..., d_in + N:]
+    xs, k, q = _heads(xbc, cfg)                          # (B, S, H, ...)
     dt = jax.nn.softplus(dt.astype(jnp.float32)
                          + params["dt_bias"])            # (B, S, H)
     A = -jnp.exp(params["a_log"])                        # (H,)
     a = jnp.exp(dt * A)                                  # decay in (0,1]
-    k = jnp.broadcast_to(Bmat[:, :, None, :], (B, S, H, N))
-    q = jnp.broadcast_to(Cmat[:, :, None, :], (B, S, H, N))
     v = xs * dt[..., None]
     y, _ = chunked_linear_scan(a, k, v, q, chunk=chunk)
     y = y + xs.astype(jnp.float32) * params["d_skip"][None, None, :, None]
-    y = y.reshape(B, S, d_in).astype(x.dtype)
-    y = rms_norm(y * jax.nn.silu(z), params["norm"])
-    return y @ params["out_proj"]
+    y = _gated_norm(y.reshape(B, S, d_in), z, params["norm"], cfg)
+    return y.astype(x.dtype) @ params["out_proj"]
 
 
-def init_mamba2_cache(cfg: ArchConfig, batch: int):
-    d_in = cfg.ssm_expand * cfg.d_model
-    N = cfg.ssm_state
-    H = d_in // cfg.ssm_head_dim
-    P = cfg.ssm_head_dim
-    conv_ch = d_in + 2 * N
+def init_mamba2_cache(cfg: ArchConfig, batch: int, layers: int):
+    """Every layer's SSM state (float32) and convolution window."""
+    _, H, P, _, N, conv_ch = _dims(cfg)
     return {
-        "ssm": jnp.zeros((batch, H, N, P), jnp.float32),
-        "conv": jnp.zeros((batch, cfg.ssm_conv - 1, conv_ch), jnp.bfloat16),
+        "ssm": jnp.zeros((layers, batch, H, N, P), jnp.float32),
+        "conv": jnp.zeros((layers, batch, cfg.ssm_conv - 1, conv_ch),
+                          jnp.bfloat16),
     }
 
 
 def mamba2_decode(params: Dict[str, jax.Array], x: jax.Array, cache: Dict,
                   cfg: ArchConfig) -> Tuple[jax.Array, Dict]:
-    """One-token step.  x: (B, 1, d)."""
+    """One-token step of one layer.  x: (B, 1, d); cache: this layer's
+    ``ssm`` (B, H, N, P) and ``conv`` (B, K - 1, C)."""
     B = x.shape[0]
-    P = cfg.ssm_head_dim
-    proj = x @ params["in_proj"]
-    z, xbc, dt, d_in, N, H = _split_proj(proj, cfg)
+    d_in = _dims(cfg)[0]
+    z, xbc, dt = _split_proj(x @ params["in_proj"], cfg)
     # conv over the cached window + current token
     win = jnp.concatenate([cache["conv"], xbc.astype(cache["conv"].dtype)],
                           axis=1)                        # (B, K, C)
     w = params["conv_w"]
-    conv = jax.nn.silu((win * w[None]).sum(axis=1, keepdims=True)
-                       + params["conv_b"])
-    xs = conv[..., :d_in].reshape(B, H, P)
-    Bmat = conv[:, 0, d_in:d_in + N]
-    Cmat = conv[:, 0, d_in + N:]
+    conv = jax.nn.silu((win * w[None]).sum(axis=1) + params["conv_b"])
+    xs, k, q = _heads(conv, cfg)                         # (B, H, ...)
     dt = jax.nn.softplus(dt[:, 0].astype(jnp.float32) + params["dt_bias"])
     A = -jnp.exp(params["a_log"])
     a = jnp.exp(dt * A)                                  # (B, H)
-    k = jnp.broadcast_to(Bmat[:, None, :], (B, H, N))
-    q = jnp.broadcast_to(Cmat[:, None, :], (B, H, N))
     v = xs * dt[..., None]
     y, new_state = linear_scan_step(cache["ssm"], a, k, v, q)
     y = y + xs.astype(jnp.float32) * params["d_skip"][None, :, None]
-    y = y.reshape(B, 1, d_in).astype(x.dtype)
-    y = rms_norm(y * jax.nn.silu(z), params["norm"])
+    y = _gated_norm(y.reshape(B, 1, d_in), z, params["norm"], cfg)
     new_cache = {"ssm": new_state, "conv": win[:, 1:]}
-    return y @ params["out_proj"], new_cache
+    return y.astype(x.dtype) @ params["out_proj"], new_cache

@@ -12,6 +12,7 @@ only one process at a time may load the TPU library, and the test workers
 all import this file.
 """
 
+import dataclasses
 import os
 import re
 
@@ -92,14 +93,15 @@ def test_ssd_scan_compiles_at_zamba2_width(one_chip):
     _assert_kernel(ssd_scan.lower(a, kq, v, kq).compile())
 
 
-def _serve_step(name, one_chip, monkeypatch, B=8, max_seq=2048):
+def _serve_step(name, one_chip, monkeypatch, B=8, max_seq=2048, **cut):
     """The serving step at full width, compiled for one v5e as ServeEngine
     jits it (cache donated); returns (compiled, params, cache) specs.  The
     step pins its carried cache to the default device's argument layout, so
-    that device is the described chip here, as it is on the chip."""
+    that device is the described chip here, as it is on the chip.  ``cut``
+    replaces fields of the configuration (a depth cut)."""
     monkeypatch.setattr(jax, "devices",
                         lambda *_, **__: list(one_chip.device_set))
-    cfg = get_config(name)
+    cfg = dataclasses.replace(get_config(name), **cut)
     place = lambda s: _spec(s.shape, s.dtype, one_chip)   # noqa: E731
     params = jax.tree_util.tree_map(place, jax.eval_shape(
         lambda: lm.init_params(cfg, jax.random.PRNGKey(0))))
@@ -139,3 +141,45 @@ def test_decode_step_updates_its_cache_in_place(one_chip, monkeypatch, name):
     shape = ",".join(map(str, cache["k"].shape))
     whole_copy = re.compile(r"%copy[\w.-]* = \w+\[" + shape + r"\]")
     assert not whole_copy.search(compiled.as_text())
+
+
+def _producers(text, shape):
+    """(opcode, rest of the line) of every instruction of ``text`` that
+    defines an array of ``shape``."""
+    dims = ",".join(map(str, shape))
+    return set(re.findall(r"%[\w.-]+ = \w+\[" + dims
+                          + r"\]\{[^}]*\} ([\w-]+)\((.*)", text))
+
+
+PASS_THROUGH = {"parameter", "get-tuple-element", "tuple", "bitcast"}
+
+
+def test_zamba2_7b_stage_decode_step_fits_and_copies_no_stack(one_chip,
+                                                              monkeypatch):
+    """The zamba2-7b stage the benchmark serves (layers 0-41, both shared
+    blocks) at B=8, max_seq=2048, as ServeEngine jits it: it fits one v5e,
+    its output aliases the whole donated cache, and each step writes one
+    KV entry per application and one state slice per layer in place.  No
+    instruction makes a whole KV, SSM-state or conv stack other than by
+    writing into it, no temporary is as large as one, and no Mamba2 weight
+    is read as a chunk of several layers."""
+    compiled, params, cache = _serve_step("zamba2-7b", one_chip, monkeypatch,
+                                          n_layers=42)
+    mem = compiled.memory_analysis()
+    assert _nbytes(params) > 8.3e9                  # 4.196 B parameters
+    footprint = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                 + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert footprint < HBM_BYTES
+    assert mem.alias_size_in_bytes >= _nbytes(cache)
+    assert mem.temp_size_in_bytes < min(_nbytes(c) for c in
+                                        (cache["ssm"], cache["k"]))
+    text = compiled.as_text()
+    for leaf in cache.values():             # written, by op or by fusion
+        for op, rest in _producers(text, leaf.shape):
+            assert op in PASS_THROUGH or op == "dynamic-update-slice" or (
+                op == "fusion" and "/dynamic_update_slice" in rest), rest
+    for w in (params["mamba_blocks"]["in_proj"],
+              params["mamba_blocks"]["out_proj"]):
+        for k in range(2, w.shape[0] + 1):
+            for op, rest in _producers(text, (k,) + w.shape[1:]):
+                assert op in PASS_THROUGH, rest
